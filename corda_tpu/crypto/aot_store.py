@@ -51,9 +51,13 @@ def enabled() -> bool:
 
 
 def store_dir() -> str:
-    return os.environ.get(
-        "CORDA_TPU_AOT_DIR",
-        os.path.join(tempfile.gettempdir(), "corda_tpu_aot"),
+    """`CORDA_TPU_AOT_DIR` when set, else a subdirectory of the
+    persistent compile cache (utils/jaxenv.compile_cache_dir), so one
+    outside setting places both."""
+    from ..utils import jaxenv
+
+    return os.environ.get("CORDA_TPU_AOT_DIR") or os.path.join(
+        jaxenv.compile_cache_dir(), "corda_tpu_aot"
     )
 
 

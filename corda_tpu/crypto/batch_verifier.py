@@ -48,25 +48,6 @@ class VerificationRequest:
     message: bytes
 
 
-def _shard_map(fn, *, mesh, in_specs, out_specs, check_vma):
-    """jax.shard_map across the supported jax range: 0.4.x ships it as
-    jax.experimental.shard_map with the replication check named
-    check_rep instead of check_vma; newer jax promotes it to the top
-    level with the new kwarg. Same program either way."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as esm
-
-    return esm(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
-
 class _AotLadder:
     """Lazy AOT wrapper around one jitted ladder program.
 
@@ -76,17 +57,21 @@ class _AotLadder:
     jit fn (the ONE trace it would have done anyway) and saves the
     artifact for every later process. Any failure anywhere falls back
     permanently to the plain jit path; CORDA_TPU_AOT=0 bypasses the
-    store entirely."""
+    store entirely. `origin` says which path the first call took:
+    "aot-hit" (artifact loaded), "aot-saved" (exported and saved
+    now) or "jit"."""
 
     def __init__(self, fn, scheme_id: int, batch: int):
         self._fn = fn
         self._scheme_id = scheme_id
         self._batch = batch
         self._callable = None
+        self.origin: Optional[str] = None
 
     def _build(self, staged):
         from . import aot_store
 
+        self.origin = "jit"
         if not aot_store.enabled():
             return self._fn
         from jax import export as jexport
@@ -98,6 +83,9 @@ class _AotLadder:
                 aot_store.save(exp, self._scheme_id, self._batch)
             except Exception:
                 return self._fn
+            self.origin = "aot-saved"
+        else:
+            self.origin = "aot-hit"
         call = jax.jit(exp.call)
 
         def run(**kw):
@@ -120,6 +108,7 @@ class _AotLadder:
                 raise
             # poisoned/incompatible artifact path: pin the jit fallback
             self._callable = self._fn
+            self.origin = "jit"
             return self._fn(**staged)
 
 
@@ -255,7 +244,7 @@ class TpuBatchVerifier(BatchSignatureVerifier):
                 # replicated constants and become shard-varying, which
                 # the VMA checker rejects; the program is collective-
                 # free so the check buys nothing here
-                smapped = _shard_map(
+                smapped = jax.shard_map(
                     partial(inner, use_pallas=mesh_use_pallas),
                     mesh=self.mesh,
                     in_specs=in_specs,
@@ -269,6 +258,14 @@ class TpuBatchVerifier(BatchSignatureVerifier):
                 )
             self._kernels[key] = fn
         return self._kernels[key]
+
+    def kernel_origins(self) -> dict:
+        """{(scheme_id, batch): "aot-hit" | "aot-saved" | "jit" |
+        "mesh"} for every program this verifier has dispatched."""
+        return {
+            key: getattr(fn, "origin", None) or "mesh"
+            for key, fn in self._kernels.items()
+        }
 
     def _pick_batch(self, n: int) -> int:
         for b in self.batch_sizes:
@@ -477,10 +474,8 @@ class PendingVerification:
                     for j, ok in zip(chunk_idxs, vals):
                         out[j] = ok
             elif pending:
-                # ONE device->host fetch for all chunks: on a
-                # remote-attached TPU each fetch pays ~50-100 ms of link
-                # latency, so per-chunk np.asarray calls would serialise
-                # round-trips the concatenation avoids
+                # ONE device->host fetch for all chunks instead of a
+                # blocking np.asarray round trip per chunk
                 flat = np.asarray(
                     jnp.concatenate([res for res, _, _ in pending])
                 )
@@ -490,8 +485,9 @@ class PendingVerification:
                     off += res.shape[0]
                     for j, ok in enumerate(arr[:n].tolist()):
                         out[chunk_idxs[j]] = bool(ok)
-            # only mark done once the fetch succeeded: a transient link
-            # failure must surface on retry, not hand back None rows
+            # only mark done once the fetch succeeded: a transient
+            # device failure must surface on retry, not hand back None
+            # rows
             self._out = [bool(v) for v in out]
             self._pending = None
             self._done = True

@@ -259,9 +259,9 @@ def stage_ecdsa_packed(
 
     The wire format to the device is raw 32-byte big-endian field
     elements (z, r, s, qx, qy) — 160 B/signature vs ~530 B for the limb
-    staging — because on a remote-attached TPU the host<->device link is
-    the bottleneck, not the MXU/VPU (measured: the 4096-batch limb form
-    moves 2.1 MB for ~0.6 ms of device compute). Limb expansion, range
+    staging — so the host->device transfer stays small next to the
+    device compute (the 4096-batch limb form would move 2.1 MB per
+    chunk). Limb expansion, range
     checks (0 < r,s < n), coordinate bounds and the on-curve check all
     run on device; the host keeps only what it must: strict DER parsing
     (variable-length, consensus-critical — same code path as the CPU

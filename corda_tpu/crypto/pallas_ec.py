@@ -16,18 +16,18 @@ The field/point arithmetic is the same code XLA traces
 Montgomery multiply, carry rounds and the complete RCB15 addition all
 reuse the exact implementations the CPU-mesh tests verify bit-exactly.
 
-Bit scan: scalars arrive as canonical [22, B] radix-2^12 digit arrays;
-the outer `fori_loop` walks limbs MSB-first (dynamic row read from the
-VMEM ref), the inner 12 bit-steps are unrolled at trace time. Scanning
-all 264 limb-bits (vs 256) costs +3% point ops and keeps indexing
-static — scalars are < 2^256 so the top bits add the identity, which
-the complete formulas absorb.
+Bit scan: scalars arrive as canonical [22, B] radix-2^12 digit arrays,
+handed to the kernel as [22, 1, B] so a digit row is read by a dynamic
+index on the untiled leading axis; the outer `fori_loop` walks limbs
+MSB-first and the inner one the 12 bits (or three 4-bit windows) of a
+row, so the ladder body traces once. Scanning all 264 limb-bits (vs
+256) costs +3% point ops — scalars are < 2^256 so the top bits add the
+identity, which the complete formulas absorb.
 """
 
 from __future__ import annotations
 
 import os
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -91,14 +91,65 @@ def use_windowed_ladder(curve_tag: str = "p256") -> bool:
     return _WINDOWED_DEFAULT.get(curve_tag, False)
 
 
-def _fit_block(batch: int, block: int) -> int:
-    """Largest divisor of `batch` that is <= `block`: ~1 MB of ladder
-    state per 256 signatures, so a silent block=batch fallback for odd
-    batch sizes would blow VMEM (e.g. batch 6000 -> ~23 MB)."""
-    block = min(block, batch)
-    while batch % block:
-        block -= 1
-    return block
+LANE = 128
+
+
+def _fit_block(batch: int, block: int) -> tuple[int, int]:
+    """(padded batch, lane block) for a [22, batch] ladder. A batch no
+    wider than the block runs as ONE block spanning it (a block equal
+    to the array dimension is always legal). Otherwise the block is
+    rounded down to a multiple of the 128-lane tile — Mosaic refuses
+    any other lane block — and the batch pads up to whole blocks, so
+    an odd batch never falls back to one VMEM-blowing block."""
+    if batch <= block:
+        return batch, batch
+    block = max(LANE, block - block % LANE)
+    return -(-batch // block) * block, block
+
+
+def _ladder_call(kernel, scalars, points, batch, padded, block, n_out,
+                 interpret):
+    """pallas_call over [22, padded] operands in [22, block] tiles;
+    pads the lane axis on the way in and slices it off on the way out
+    (padding lanes compute garbage on zero inputs, never read). The two
+    scalar digit arrays enter as [22, 1, padded], so the kernel reads
+    a digit row by a dynamic index on the untiled leading axis (see
+    _scan_limbs)."""
+    pad = ((0, 0), (0, padded - batch))
+    if padded != batch:
+        scalars = [jnp.pad(x, pad) for x in scalars]
+        points = [jnp.pad(x, pad) for x in points]
+    scalars = [x.reshape(NLIMB, 1, padded) for x in scalars]
+    digit_spec = pl.BlockSpec((NLIMB, 1, block), lambda i: (0, 0, i))
+    spec = pl.BlockSpec((NLIMB, block), lambda i: (0, i))
+    shape = jax.ShapeDtypeStruct((NLIMB, padded), jnp.int32)
+    out = pl.pallas_call(
+        kernel,
+        grid=(padded // block,),
+        in_specs=[digit_spec] * len(scalars) + [spec] * len(points),
+        out_specs=(spec,) * n_out,
+        out_shape=(shape,) * n_out,
+        interpret=interpret,
+    )(*scalars, *points)
+    if padded != batch:
+        out = tuple(o[:, :batch] for o in out)
+    return out
+
+
+def _scan_limbs(s1_ref, s2_ref, limbs, acc, limb_step):
+    """Walk the digit rows limbs-1 .. 0 (MSB first), folding
+    `limb_step(row1, row2, acc)` over them. The digit refs are
+    [NLIMB, 1, block]: Mosaic has no dynamic sublane indexing, but a
+    dynamic index on the untiled leading axis is a plain address
+    offset, so the walk is a fori_loop and the ladder body traces once
+    rather than once per limb (22x less to trace and compile)."""
+
+    def body(i, acc):
+        limb = limbs - 1 - i
+        with scalar_consts_mode():
+            return limb_step(s1_ref[limb, 0, :], s2_ref[limb, 0, :], acc)
+
+    return lax.fori_loop(0, limbs, body, acc)
 
 
 def _g_mont_limbs(curve: WeierstrassCurve, batch: int):
@@ -127,7 +178,7 @@ def wei_ladder_pallas(
     runs of the full kernel tractable on CPU; production always scans
     all NLIMB rows."""
     batch = u1.shape[1]
-    block = _fit_block(batch, _block_or_default(block))
+    padded, block = _fit_block(batch, _block_or_default(block))
 
     def kernel(u1_ref, u2_ref, qx_ref, qy_ref, x_ref, y_ref, z_ref):
         # scalar-consts mode: Pallas rejects captured array constants,
@@ -140,17 +191,10 @@ def wei_ladder_pallas(
             GQ = ec.wei_add(curve, G, Q)
             inf = ec.wei_infinity(ctx, block)
 
-            # outer loop over limbs is unrolled (static ref row reads —
-            # Mosaic has no dynamic sublane indexing); the inner 12-bit
-            # walk is a fori_loop (shift by a traced amount is a plain
-            # VPU op), keeping the program ~22 traced bodies rather
-            # than 264
-            acc = inf
-            for limb in range(limbs - 1, -1, -1):
-                row1 = u1_ref[limb, :]
-                row2 = u2_ref[limb, :]
-
-                def step(j, acc, row1=row1, row2=row2):
+            # per limb row, the 12-bit walk is a fori_loop (shift by a
+            # traced amount is a plain VPU op)
+            def limb_step(row1, row2, acc):
+                def step(j, acc):
                     bit = LIMB_BITS - 1 - j
                     with scalar_consts_mode():
                         acc = ec.wei_add(curve, acc, acc)
@@ -161,22 +205,16 @@ def wei_ladder_pallas(
                         P = ec.wei_select(bq, hi, lo)
                         return ec.wei_add(curve, acc, P)
 
-                acc = lax.fori_loop(0, LIMB_BITS, step, acc)
-            X, Y, Z = acc
+                return lax.fori_loop(0, LIMB_BITS, step, acc)
+
+            X, Y, Z = _scan_limbs(u1_ref, u2_ref, limbs, inf, limb_step)
             x_ref[:] = X
             y_ref[:] = Y
             z_ref[:] = Z
 
-    spec = pl.BlockSpec((NLIMB, block), lambda i: (0, i))
-    shape = jax.ShapeDtypeStruct((NLIMB, batch), jnp.int32)
-    return pl.pallas_call(
-        kernel,
-        grid=(batch // block,),
-        in_specs=[spec, spec, spec, spec],
-        out_specs=(spec, spec, spec),
-        out_shape=(shape, shape, shape),
-        interpret=interpret,
-    )(u1, u2, qx_m, qy_m)
+    return _ladder_call(
+        kernel, (u1, u2), (qx_m, qy_m), batch, padded, block, 3, interpret
+    )
 
 
 def wei_ladder_windowed_pallas(
@@ -200,7 +238,7 @@ def wei_ladder_windowed_pallas(
     VMEM: the Q table adds 16 x 3 x [22, block] int32 (~1.4 MB at block
     128) on top of the ladder state; G entries are scalar consts."""
     batch = u1.shape[1]
-    block = _fit_block(batch, _block_or_default(block))
+    padded, block = _fit_block(batch, _block_or_default(block))
 
     def kernel(u1_ref, u2_ref, qx_ref, qy_ref, x_ref, y_ref, z_ref):
         with scalar_consts_mode():
@@ -209,12 +247,8 @@ def wei_ladder_windowed_pallas(
             inf = ec.wei_infinity(ctx, block)
             g_tab, q_tab = ec.wei_window_tables(curve, Q, block, w=4)
 
-            acc = inf
-            for limb in range(limbs - 1, -1, -1):
-                row1 = u1_ref[limb, :]
-                row2 = u2_ref[limb, :]
-
-                def win_step(j, acc, row1=row1, row2=row2):
+            def limb_step(row1, row2, acc):
+                def win_step(j, acc):
                     shift = LIMB_BITS - 4 - 4 * j      # 8, 4, 0
                     with scalar_consts_mode():
                         for _ in range(4):
@@ -228,22 +262,16 @@ def wei_ladder_windowed_pallas(
                             curve, acc, ec.wei_table_select(d2, q_tab)
                         )
 
-                acc = lax.fori_loop(0, LIMB_BITS // 4, win_step, acc)
-            X, Y, Z = acc
+                return lax.fori_loop(0, LIMB_BITS // 4, win_step, acc)
+
+            X, Y, Z = _scan_limbs(u1_ref, u2_ref, limbs, inf, limb_step)
             x_ref[:] = X
             y_ref[:] = Y
             z_ref[:] = Z
 
-    spec = pl.BlockSpec((NLIMB, block), lambda i: (0, i))
-    shape = jax.ShapeDtypeStruct((NLIMB, batch), jnp.int32)
-    return pl.pallas_call(
-        kernel,
-        grid=(batch // block,),
-        in_specs=[spec, spec, spec, spec],
-        out_specs=(spec, spec, spec),
-        out_shape=(shape, shape, shape),
-        interpret=interpret,
-    )(u1, u2, qx_m, qy_m)
+    return _ladder_call(
+        kernel, (u1, u2), (qx_m, qy_m), batch, padded, block, 3, interpret
+    )
 
 
 def ed_ladder_windowed_pallas(
@@ -261,7 +289,7 @@ def ed_ladder_windowed_pallas(
     add from the constant base-point table + one from the per-block
     A-multiple table)."""
     batch = s.shape[1]
-    block = _fit_block(batch, _block_or_default(block))
+    padded, block = _fit_block(batch, _block_or_default(block))
 
     def kernel(s_ref, k_ref, ax_ref, ay_ref, x_ref, y_ref, z_ref, t_ref):
         with scalar_consts_mode():
@@ -270,12 +298,8 @@ def ed_ladder_windowed_pallas(
             ident = ec.ed_identity(ctx, block)
             b_tab, a_tab = ec.ed_window_tables(curve, A, block, w=4)
 
-            acc = ident
-            for limb in range(limbs - 1, -1, -1):
-                row_s = s_ref[limb, :]
-                row_k = k_ref[limb, :]
-
-                def win_step(j, acc, row_s=row_s, row_k=row_k):
+            def limb_step(row_s, row_k, acc):
+                def win_step(j, acc):
                     shift = LIMB_BITS - 4 - 4 * j      # 8, 4, 0
                     with scalar_consts_mode():
                         for _ in range(4):
@@ -289,23 +313,17 @@ def ed_ladder_windowed_pallas(
                             curve, acc, ec.ed_table_select(d2, a_tab)
                         )
 
-                acc = lax.fori_loop(0, LIMB_BITS // 4, win_step, acc)
-            X, Y, Z, T = acc
+                return lax.fori_loop(0, LIMB_BITS // 4, win_step, acc)
+
+            X, Y, Z, T = _scan_limbs(s_ref, k_ref, limbs, ident, limb_step)
             x_ref[:] = X
             y_ref[:] = Y
             z_ref[:] = Z
             t_ref[:] = T
 
-    spec = pl.BlockSpec((NLIMB, block), lambda i: (0, i))
-    shape = jax.ShapeDtypeStruct((NLIMB, batch), jnp.int32)
-    return pl.pallas_call(
-        kernel,
-        grid=(batch // block,),
-        in_specs=[spec, spec, spec, spec],
-        out_specs=(spec, spec, spec, spec),
-        out_shape=(shape, shape, shape, shape),
-        interpret=interpret,
-    )(s, k, ax_m, ay_m)
+    return _ladder_call(
+        kernel, (s, k), (ax_m, ay_m), batch, padded, block, 4, interpret
+    )
 
 
 def ed_ladder_pallas(
@@ -316,12 +334,14 @@ def ed_ladder_pallas(
     ay_m,               # [22, B]
     block: int | None = None,
     interpret: bool = False,
+    limbs: int = NLIMB,
 ):
     """R = s*B + k*A on the twisted Edwards curve (B = base point),
     VMEM-resident per block like the Weierstrass ladder; returns
-    extended coordinates (X, Y, Z, T) in Montgomery domain."""
+    extended coordinates (X, Y, Z, T) in Montgomery domain. `limbs`
+    as in wei_ladder_pallas (test-only reduced scan)."""
     batch = s.shape[1]
-    block = _fit_block(batch, _block_or_default(block))
+    padded, block = _fit_block(batch, _block_or_default(block))
 
     R = 1 << R_BITS
 
@@ -335,12 +355,8 @@ def ed_ladder_pallas(
             BA = ec.ed_add(curve, Bp, A)
             ident = ec.ed_identity(ctx, block)
 
-            acc = ident
-            for limb in range(NLIMB - 1, -1, -1):
-                row_s = s_ref[limb, :]
-                row_k = k_ref[limb, :]
-
-                def step(j, acc, row_s=row_s, row_k=row_k):
+            def limb_step(row_s, row_k, acc):
+                def step(j, acc):
                     bit = LIMB_BITS - 1 - j
                     with scalar_consts_mode():
                         acc = ec.ed_add(curve, acc, acc)
@@ -351,20 +367,14 @@ def ed_ladder_pallas(
                         P = ec.ed_select(bk, hi, lo)
                         return ec.ed_add(curve, acc, P)
 
-                acc = lax.fori_loop(0, LIMB_BITS, step, acc)
-            X, Y, Z, T = acc
+                return lax.fori_loop(0, LIMB_BITS, step, acc)
+
+            X, Y, Z, T = _scan_limbs(s_ref, k_ref, limbs, ident, limb_step)
             x_ref[:] = X
             y_ref[:] = Y
             z_ref[:] = Z
             t_ref[:] = T
 
-    spec = pl.BlockSpec((NLIMB, block), lambda i: (0, i))
-    shape = jax.ShapeDtypeStruct((NLIMB, batch), jnp.int32)
-    return pl.pallas_call(
-        kernel,
-        grid=(batch // block,),
-        in_specs=[spec, spec, spec, spec],
-        out_specs=(spec, spec, spec, spec),
-        out_shape=(shape, shape, shape, shape),
-        interpret=interpret,
-    )(s, k, ax_m, ay_m)
+    return _ladder_call(
+        kernel, (s, k), (ax_m, ay_m), batch, padded, block, 4, interpret
+    )

@@ -174,6 +174,16 @@ class Node:
             from ..crypto.batch_verifier import CpuBatchVerifier
 
             batch_verifier = CpuBatchVerifier()
+        elif batch_verifier is None:
+            # a "tpu" node never serves from a backend it did not ask
+            # for: no TPU means no boot, unless the process was pinned
+            # to the CPU on purpose (tests, JAX_PLATFORMS=cpu)
+            from ..utils import jaxenv
+
+            jaxenv.require_tpu(
+                f"node {config.name} (verifier_backend='tpu')",
+                allow_pinned_cpu=True,
+            )
 
         # -- services over one shared database -------------------------
         self.services = PersistentServiceHub.open(
@@ -819,20 +829,15 @@ class Node:
                     # when this process actually sees several devices
                     # (N unpinned copies on one chip would just pay N
                     # jit caches for the same dispatch queue)
-                    try:
-                        import jax
+                    import jax
 
-                        from ..crypto.batch_verifier import (
-                            per_shard_verifiers,
+                    from ..crypto.batch_verifier import per_shard_verifiers
+
+                    devices = jax.devices()
+                    if len(devices) > 1:
+                        shard_verifiers = per_shard_verifiers(
+                            shards, devices=devices
                         )
-
-                        devices = jax.devices()
-                        if len(devices) > 1:
-                            shard_verifiers = per_shard_verifiers(
-                                shards, devices=devices
-                            )
-                    except Exception:
-                        shard_verifiers = None   # shared SPI verifier
                 if self.config.qos_enabled:
                     self._build_qos()
                 intent_journal = None

@@ -2168,10 +2168,9 @@ class BatchingNotaryService(NotaryService):
                 and getattr(self.uniqueness, "batch_synchronous", False)
             )
             if handle is not None and not stream_ok:
-                # collect on a worker thread: on a remote-attached
-                # device the d2h result fetch is GIL-releasing link IO
-                # (~100 ms), which this overlaps with the contract loop
-                # below instead of serialising after it
+                # collect on a worker thread: the d2h result fetch is
+                # GIL-releasing device IO, which this overlaps with the
+                # contract loop below instead of serialising after it
                 def _collect() -> None:
                     try:
                         box["results"] = handle.result()

@@ -52,8 +52,12 @@ def _public_members(module) -> Iterable[str]:
     for name, obj in sorted(vars(module).items()):
         if name.startswith("_"):
             continue
-        # only symbols defined here (imports are not this module's API)
+        # only symbols defined here (imports are not this module's API),
+        # at module level: a class built inside a function and bound to
+        # a global at run time would make the scan depend on what ran
         if getattr(obj, "__module__", None) != mod_name:
+            continue
+        if "<locals>" in getattr(obj, "__qualname__", ""):
             continue
         if inspect.isclass(obj):
             bases = [

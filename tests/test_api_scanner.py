@@ -34,3 +34,23 @@ def test_api_surface_matches_committed_file():
     assert os.path.exists(api_scanner.default_path())
     diff = api_scanner.check()
     assert not diff, "\n".join(diff)
+
+
+def test_classes_bound_at_run_time_stay_out():
+    """A class built inside a function and bound to a module global
+    (utils/health.py's canary types) is not surface: the scan must not
+    depend on which code ran first in the process."""
+    import types
+
+    mod = types.ModuleType("corda_tpu._scan_probe")
+
+    def install():
+        class Local:
+            def method(self):
+                pass
+
+        Local.__module__ = mod.__name__
+        mod.Bound = Local
+
+    install()
+    assert list(api_scanner._public_members(mod)) == []

@@ -106,7 +106,17 @@ def test_shipped_contracts_pass_determinism_audit():
     import corda_tpu.finance  # noqa: F401
     import corda_tpu.samples.irs_demo  # noqa: F401
 
-    offenders = determinism.audit_registered_contracts()
+    from corda_tpu.core.contracts import _CONTRACT_REGISTRY
+
+    # only what the package ships: tests/test_docs_snippets.py registers
+    # docs.* contracts from exec'd source, which has no file to audit
+    offenders = {
+        name: v
+        for name, v in determinism.audit_registered_contracts().items()
+        if getattr(_CONTRACT_REGISTRY[name], "__module__", "").startswith(
+            "corda_tpu"
+        )
+    }
     assert offenders == {}, offenders
 
 

@@ -5,8 +5,9 @@ modmath/ec code as the XLA path, but under `scalar_consts_mode`, which
 swaps constant handling (python-int rebuilds instead of embedded
 arrays / the int8 MXU matmul) and scatter-free accumulation (Mosaic has
 no scatter-add / value dynamic-slice lowering). These tests pin the two
-tracing modes to identical values on CPU; the TPU-side bit-exactness of
-the full kernel is asserted by bench.py's CPU spot-check on every run.
+tracing modes to identical values on CPU, and run the ladders whole in
+interpret mode against the pure-python reference; on the chip,
+chip_smoke.py checks the kernels bit-exact against the CPU reference.
 """
 
 import random
@@ -85,3 +86,67 @@ def test_pallas_routing_flag(monkeypatch):
     assert _use_pallas_ladder() is False
     monkeypatch.setenv("CORDA_TPU_NO_PALLAS", "1")
     assert _use_pallas_ladder() is False
+
+
+def _affine(fp, p_mod, X, Y, Z):
+    """Montgomery projective limb arrays -> affine python ints."""
+    xs, ys, zs = (L.batch_to_ints(mm.from_mont(fp, v)) for v in (X, Y, Z))
+    return [
+        (x * pow(z, -1, p_mod) % p_mod, y * pow(z, -1, p_mod) % p_mod)
+        for x, y, z in zip(xs, ys, zs)
+    ]
+
+
+def _ladder_case(name):
+    from corda_tpu.crypto import pallas_ec, refmath
+    from corda_tpu.crypto.curves import ED25519
+
+    rng = random.Random(53)
+    B = 2
+    if name == "ed25519-plain":
+        curve = ED25519
+        pts = [refmath.ed_mul(curve, rng.randrange(1, curve.L),
+                              (curve.gx, curve.gy)) for _ in range(B)]
+        order, ladder = curve.L, pallas_ec.ed_ladder_pallas
+
+        def want(a, b, P):
+            return refmath.ed_add(
+                curve, refmath.ed_mul(curve, a, (curve.gx, curve.gy)),
+                refmath.ed_mul(curve, b, P),
+            )
+    else:
+        curve = SECP256K1 if name == "k1-plain" else SECP256R1
+        pts = [refmath.wei_mul(curve, rng.randrange(1, curve.n),
+                               (curve.gx, curve.gy)) for _ in range(B)]
+        order = curve.n
+        ladder = (pallas_ec.wei_ladder_windowed_pallas
+                  if name == "p256-windowed" else pallas_ec.wei_ladder_pallas)
+
+        def want(a, b, P):
+            return refmath.wei_add(
+                curve, refmath.wei_mul(curve, a, (curve.gx, curve.gy)),
+                refmath.wei_mul(curve, b, P),
+            )
+    a_s = [rng.randrange(1, order) for _ in range(B)]
+    b_s = [rng.randrange(1, order) for _ in range(B)]
+    args = [jnp.asarray(L.ints_to_batch(v)) for v in (a_s, b_s)] + [
+        mm.to_mont(curve.fp, jnp.asarray(L.ints_to_batch([p[i] for p in pts])))
+        for i in (0, 1)
+    ]
+    expect = [want(a, b, P) for a, b, P in zip(a_s, b_s, pts)]
+    return curve, ladder, args, expect
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize(
+    "name", ["p256-windowed", "p256-plain", "k1-plain", "ed25519-plain"]
+)
+def test_full_width_ladder_interpret_matches_reference(name):
+    """Every ladder the verifier can pick, all 22 limb rows of 256-bit
+    scalars, against the pure-python reference (about a minute each in
+    interpret mode; tier-1 compiles the ladders in tests/
+    test_tpu_compile.py and runs them through chip_smoke's phases)."""
+    curve, ladder, args, expect = _ladder_case(name)
+    out = ladder(curve, *args, block=2, interpret=True)
+    got = _affine(curve.fp, curve.p, *out[:3])
+    assert got == expect

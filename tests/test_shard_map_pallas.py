@@ -8,14 +8,14 @@ ladder. These tests prove the combination on the CPU mesh:
 * the SPI mesh branch (shard_map'd XLA on CPU, shard_map'd Pallas on a
   TPU backend) is covered by tests/test_mesh_verifier.py;
 * here, the Pallas kernel itself runs INSIDE shard_map in interpret
-  mode with a reduced 1-limb scan (full 22-limb interpret runs take
-  >400 s) and must match the XLA ladder bit-for-bit — same formulas,
-  same step order, so projective outputs are identical, not just
-  equivalent.
+  mode with a reduced 1-limb scan and must match the XLA ladder
+  bit-for-bit — same formulas, same step order, so projective outputs
+  are identical, not just equivalent (full-width interpret runs are
+  in tests/test_pallas_path.py).
 
-On real hardware the full-path proof is __graft_entry__.dryrun_multichip
-plus a 1-chip-mesh TpuBatchVerifier run (exercised in round-2 bring-up:
-16/16 rows bit-exact vs CpuBatchVerifier).
+On real chips the full path is `python chip_smoke.py --chips 4`: a
+4-device mesh verify of a 4096x4 chunk, held to construction and the
+CPU reference.
 """
 
 import random
