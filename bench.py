@@ -272,6 +272,7 @@ def _notary_rate(
         InMemoryUniquenessProvider,
         ShardedUniquenessProvider,
     )
+    from corda_tpu.utils.perf import flush_phase_seconds
 
     shard_verifiers = None
     if shards > 1 and verifier is not None:
@@ -316,8 +317,8 @@ def _notary_rate(
 
     try:
         run_once()                    # warm-up: compile + correctness
-        if svc.phase_seconds is not None:
-            svc.phase_seconds.clear()   # profile the timed reps only
+        # the timed reps' phases: the FlushPhase timers past warm-up
+        warm_phases = flush_phase_seconds(svc.metrics)
         # the staged fixture (pre-signed spends + their backchain) is a
         # large STATIC heap; freeze it out of the collector's
         # generations so the flush-time allocations don't drag it
@@ -336,17 +337,19 @@ def _notary_rate(
             # to the collector, and the default run's later metrics
             # must not pay the leaked memory
             gc.unfreeze()
-        if report_phases and svc.phase_seconds:
-            # CORDA_TPU_NOTARY_PROFILE=1: per-phase share of the wall
-            total = sum(svc.phase_seconds.values())
+        phases = {
+            k: row["total_s"] - warm_phases.get(k, {}).get("total_s", 0.0)
+            for k, row in flush_phase_seconds(svc.metrics).items()
+        }
+        total = sum(phases.values())
+        if report_phases and total > 0:
+            # per-phase share of the timed reps' flush wall
             print(
                 "notary flush phases "
                 + " ".join(
                     f"{k}={v * 1e6 / (batch * iters):.1f}us/tx"
                     f"({100 * v / total:.0f}%)"
-                    for k, v in sorted(
-                        svc.phase_seconds.items(), key=lambda kv: -kv[1]
-                    )
+                    for k, v in sorted(phases.items(), key=lambda kv: -kv[1])
                 ),
                 file=sys.stderr,
             )

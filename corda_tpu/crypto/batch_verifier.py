@@ -362,13 +362,13 @@ class TpuBatchVerifier(BatchSignatureVerifier):
                 acct.record_transfer(scheme_id, batch, nbytes, put_s)
                 devacct.record_transfer(dev_id, nbytes, put_s)
                 nbytes = 0   # charged above, not again on the call row
-            # TraceAnnotation (null context off-jax-profiler): names
-            # this kernel launch in an XLA profiler capture so the
-            # host-side dispatch spans line up with device timelines
+            # a profiler region over the launch while a capture is
+            # active, carrying the real and the padded rows it sends
             first = (scheme_id, batch) not in self._warm_shapes
             t_call = time.perf_counter()
             with tracing.annotate(
-                f"corda_tpu.verify_dispatch.s{scheme_id}.b{batch}"
+                "verify.launch", scheme=scheme_id, rows=len(chunk),
+                batch=batch,
             ):
                 res = self._kernel(scheme_id, batch)(**staged)
             self._warm_shapes.add((scheme_id, batch))
@@ -378,13 +378,14 @@ class TpuBatchVerifier(BatchSignatureVerifier):
                 first=first, transfer_bytes=nbytes,
             )
             # per-device attribution: the launch wall as device busy
-            # (the windowed busy-fraction feed) and the host-side
+            # (the windowed busy-fraction feed), the host-side
             # dispatch-queue wait — wall from bucket entry to this
             # chunk's launch, the serialization a chunk pays behind
-            # earlier chunks' staging + launches on the same device
+            # earlier chunks' staging + launches on the same device —
+            # and the padded rows the ladder computes for the real ones
             devacct.record_dispatch(
                 dev_id, len(chunk), call_s,
-                queue_wait_seconds=t_call - t_entry,
+                queue_wait_seconds=t_call - t_entry, rows=batch,
             )
             pending.append((res, idxs[off : off + len(chunk)], len(chunk)))
         return pending

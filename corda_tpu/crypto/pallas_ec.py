@@ -107,14 +107,24 @@ def _fit_block(batch: int, block: int) -> tuple[int, int]:
     return -(-batch // block) * block, block
 
 
+# the ladder's kernel name per curve: a profiler trace names each
+# ladder's custom call by it (`ladder_<curve>_<bit|windowed>`)
+_CURVE_TAG = {"secp256r1": "p256", "secp256k1": "k1", "ed25519": "ed25519"}
+
+
+def _ladder_name(curve, windowed: bool) -> str:
+    tag = _CURVE_TAG.get(curve.name, curve.name)
+    return f"ladder_{tag}_{'windowed' if windowed else 'bit'}"
+
+
 def _ladder_call(kernel, scalars, points, batch, padded, block, n_out,
-                 interpret):
-    """pallas_call over [22, padded] operands in [22, block] tiles;
-    pads the lane axis on the way in and slices it off on the way out
-    (padding lanes compute garbage on zero inputs, never read). The two
-    scalar digit arrays enter as [22, 1, padded], so the kernel reads
-    a digit row by a dynamic index on the untiled leading axis (see
-    _scan_limbs)."""
+                 interpret, name):
+    """pallas_call `name` over [22, padded] operands in [22, block]
+    tiles; pads the lane axis on the way in and slices it off on the
+    way out (padding lanes compute garbage on zero inputs, never read).
+    The two scalar digit arrays enter as [22, 1, padded], so the kernel
+    reads a digit row by a dynamic index on the untiled leading axis
+    (see _scan_limbs)."""
     pad = ((0, 0), (0, padded - batch))
     if padded != batch:
         scalars = [jnp.pad(x, pad) for x in scalars]
@@ -130,6 +140,7 @@ def _ladder_call(kernel, scalars, points, batch, padded, block, n_out,
         out_specs=(spec,) * n_out,
         out_shape=(shape,) * n_out,
         interpret=interpret,
+        name=name,
     )(*scalars, *points)
     if padded != batch:
         out = tuple(o[:, :batch] for o in out)
@@ -213,7 +224,8 @@ def wei_ladder_pallas(
             z_ref[:] = Z
 
     return _ladder_call(
-        kernel, (u1, u2), (qx_m, qy_m), batch, padded, block, 3, interpret
+        kernel, (u1, u2), (qx_m, qy_m), batch, padded, block, 3, interpret,
+        _ladder_name(curve, windowed=False),
     )
 
 
@@ -270,7 +282,8 @@ def wei_ladder_windowed_pallas(
             z_ref[:] = Z
 
     return _ladder_call(
-        kernel, (u1, u2), (qx_m, qy_m), batch, padded, block, 3, interpret
+        kernel, (u1, u2), (qx_m, qy_m), batch, padded, block, 3, interpret,
+        _ladder_name(curve, windowed=True),
     )
 
 
@@ -322,7 +335,8 @@ def ed_ladder_windowed_pallas(
             t_ref[:] = T
 
     return _ladder_call(
-        kernel, (s, k), (ax_m, ay_m), batch, padded, block, 4, interpret
+        kernel, (s, k), (ax_m, ay_m), batch, padded, block, 4, interpret,
+        _ladder_name(curve, windowed=True),
     )
 
 
@@ -376,5 +390,6 @@ def ed_ladder_pallas(
             t_ref[:] = T
 
     return _ladder_call(
-        kernel, (s, k), (ax_m, ay_m), batch, padded, block, 4, interpret
+        kernel, (s, k), (ax_m, ay_m), batch, padded, block, 4, interpret,
+        _ladder_name(curve, windowed=False),
     )

@@ -232,11 +232,12 @@ class DecodePool:
     def _decode_slice(self, blobs: list) -> list:
         decode = self._decode
         out = []
-        for b in blobs:
-            try:
-                out.append(decode(b))
-            except Exception as e:  # noqa: BLE001 - per-blob isolation
-                out.append(e)
+        with tracing.annotate("ingest.decode", frames=len(blobs)):
+            for b in blobs:
+                try:
+                    out.append(decode(b))
+                except Exception as e:  # noqa: BLE001 - per-blob isolation
+                    out.append(e)
         return out
 
     def decode_async(self, blobs: list) -> _SliceFuture:
@@ -269,15 +270,26 @@ class IngestRing:
         # ring get to its bound — a depth gauge samples, this remembers
         # (messaging.register_ring_gauges exports both)
         self.high_water = 0
+        # lifetime seconds `put` spent blocked on a full ring: the
+        # producer waiting on the consumer
+        self.full_wait_s = 0.0
 
     def put(self, batch, timeout: Optional[float] = None) -> bool:
         """Block until there is room (backpressure); False on timeout
-        or when the ring is closed."""
+        or when the ring is closed. Time blocked on a full ring adds to
+        `full_wait_s` and is the profiler region `ingest.ring_full`."""
         with self._cond:
-            if not self._cond.wait_for(
-                lambda: self._closed or len(self._dq) < self.depth, timeout
-            ):
-                return False
+            if not (self._closed or len(self._dq) < self.depth):
+                t0 = time.perf_counter()
+                region = tracing.open_region("ingest.ring_full")
+                room = self._cond.wait_for(
+                    lambda: self._closed or len(self._dq) < self.depth,
+                    timeout,
+                )
+                tracing.close_region(region)
+                self.full_wait_s += time.perf_counter() - t0
+                if not room:
+                    return False
             if self._closed:
                 return False
             self._dq.append(batch)
@@ -473,7 +485,11 @@ class IngestPipeline:
             )
         stxs: list[SignedTransaction] = []
         fresh: list[IngestedTx] = []
-        results = handle.result() if handle is not None else []
+        if handle is not None:
+            with tracing.annotate("ingest.decode_wait"):
+                results = handle.result()
+        else:
+            results = []
         tracer = self._tracer()
         tracing_on = tracer.enabled
         timing = (
@@ -481,6 +497,7 @@ class IngestPipeline:
             or self.txstory is not None
         )
         t_decode = time.perf_counter() if timing else 0.0
+        region = tracing.open_region("ingest.merkle_id")
         for i, obj in zip(miss_idx, results):
             blob = blobs[i]
             if isinstance(obj, Exception):
@@ -511,6 +528,8 @@ class IngestPipeline:
             [s.wtx for s in stxs], self.leaf_cache, self.root_cache
         )
         t_id = time.perf_counter() if timing else 0.0
+        tracing.close_region(region)
+        region = tracing.open_region("ingest.stage")
         cache = self.frame_cache
         for e in fresh:
             if self._stage and e.stx is not None:
@@ -526,6 +545,7 @@ class IngestPipeline:
                 if i not in shed and entries[i] is not None:
                     entries[i].deadline = d
         t_stage = time.perf_counter() if timing else 0.0
+        tracing.close_region(region, frames=len(entries))
         if self.perf is not None:
             # per-batch host-stage seconds (decode includes any overlap
             # waited out at handle.result(); hits skipped both) + frame

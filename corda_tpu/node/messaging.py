@@ -114,11 +114,14 @@ class MessagingService:
 
 def register_ring_gauges(metrics, topic: str, ring, parked_count=None) -> None:
     """Gauges over one topic's ingest ring: current depth, lifetime
-    high-water mark, and (when the fabric exposes a counter) frames
-    parked waiting for retry_parked. ONE naming scheme for every
-    fabric, so dashboards don't fork per transport."""
+    high-water mark, lifetime seconds producers spent blocked on a full
+    ring, and (when the fabric exposes a counter) frames parked waiting
+    for retry_parked. ONE naming scheme for every fabric, so dashboards
+    don't fork per transport."""
     metrics.gauge(f"Ingest.{topic}.RingDepth", lambda: len(ring))
     metrics.gauge(f"Ingest.{topic}.RingHighWater", lambda: ring.high_water)
+    metrics.gauge(f"Ingest.{topic}.RingFullWaitSeconds",
+                  lambda: ring.full_wait_s)
     if parked_count is not None:
         metrics.gauge(f"Ingest.{topic}.Parked", parked_count)
 

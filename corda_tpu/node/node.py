@@ -237,13 +237,18 @@ class Node:
         # notary so its batching counters/phase timers land on this
         # node's scrape surface. The tracer is the process default:
         # disabled unless CORDA_TPU_TRACE=1 (utils/tracing.py).
-        from ..utils import tracing
+        from ..utils import runtime, tracing
         from ..utils.health import ClusterHealth, HealthMonitor
         from ..utils.metrics import MetricRegistry
         from ..utils.perf import PerfPlane, PerfPolicy
 
         self.metrics = MetricRegistry()
         self.tracer = tracing.get_tracer()
+        # the process's collector pauses, counted for as long as this
+        # node runs and exported on its /metrics (released in stop())
+        self._gc_watch = runtime.get_gc_watch()
+        self._gc_watch.acquire()
+        runtime.register_gc_gauges(self.metrics)
         # performance-attribution plane (utils/perf.py): kernel
         # compile-vs-execute accounting (installed as the process
         # default, so every TpuBatchVerifier this node constructs
@@ -1128,6 +1133,9 @@ class Node:
             return
         self._stopped = True
         self.running = False
+        gc_watch = getattr(self, "_gc_watch", None)
+        if gc_watch is not None:
+            gc_watch.release()
         web = getattr(self, "web", None)
         if web is not None:
             web.stop()

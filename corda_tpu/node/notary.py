@@ -21,7 +21,6 @@ OutOfProcessTransactionVerifierService.kt:19-73).
 from __future__ import annotations
 
 import gc
-import os
 import threading
 from ..utils import locks
 import time
@@ -38,7 +37,7 @@ from ..core.transactions import (
 )
 from ..crypto.hashes import SecureHash
 from ..crypto.tx_signature import TransactionSignature
-from ..utils import tracing
+from ..utils import runtime, tracing
 from ..utils.metrics import MetricRegistry
 from .services import ServiceHub
 
@@ -885,6 +884,29 @@ class _NotaryShard:
         return len(self.pending)
 
 
+# the pump's states between flushes and their profiler regions
+_PUMP_REGIONS = {"hold": "notary.hold", "starved": "notary.starved"}
+
+
+class _FlushMarks(list):
+    """One flush's phase intervals [(phase, t0, t1)] in mark order,
+    and the profiler region open over the phase in progress (None off
+    a capture)."""
+
+    __slots__ = ("region",)
+
+    def __init__(self):
+        super().__init__()
+        self.region = None
+
+    def next_region(self, phase: Optional[str]) -> None:
+        """Close the open region; open `notary.<phase>` (None: none)."""
+        tracing.close_region(self.region)
+        self.region = (
+            tracing.open_region("notary." + phase) if phase else None
+        )
+
+
 class BatchingNotaryService(NotaryService):
     """Batch-committing validating notary — the north-star serving path
     (SURVEY §7 Phase 4).
@@ -1020,16 +1042,22 @@ class BatchingNotaryService(NotaryService):
         )
         # per-phase flush timers: always on (a handful of updates per
         # FLUSH, not per tx), so /metrics carries the stage breakdown
-        # continuously — the registry-backed replacement for the old
-        # env-gated phase_seconds dict
+        # continuously
         self._phase_timers: dict[str, Any] = {}
-        # CORDA_TPU_NOTARY_PROFILE=1: additionally accumulate per-phase
-        # wall seconds across flushes into a plain dict (BASELINE.md
-        # serving-profile methodology; bench.py prints it). The
-        # phase_seconds property is the back-compat view.
-        self._phase_profile: Optional[dict] = (
-            {} if os.environ.get("CORDA_TPU_NOTARY_PROFILE") else None
-        )
+        # pump state between flushes (tick): the open hold / starved
+        # episode, its start and its profiler region
+        self._pump_timers = {
+            "hold": self.metrics.timer("Notary.PumpHold"),
+            "starved": self.metrics.timer("Notary.PumpStarved"),
+        }
+        self._pump_state: Optional[str] = None
+        self._pump_since = 0.0
+        self._pump_region = None
+        # the process's collector pauses, on this registry's /metrics
+        self._gc_watch = runtime.get_gc_watch()
+        self._gc_watch.acquire()
+        self._gc_watched = True
+        runtime.register_gc_gauges(self.metrics)
         # -- fault-tolerance plane (round 9) ----------------------------
         self.degraded_fallback = degraded_fallback
         self.intent_journal = intent_journal
@@ -1106,13 +1134,6 @@ class BatchingNotaryService(NotaryService):
     @property
     def requests_batched(self) -> int:
         return self._requests_counter.count
-
-    @property
-    def phase_seconds(self) -> Optional[dict]:
-        """The CORDA_TPU_NOTARY_PROFILE accumulation dict (None when
-        profiling is off) — the live object, so callers may clear() it
-        between warm-up and timed reps as before."""
-        return self._phase_profile
 
     @property
     def effective_max_batch(self) -> int:
@@ -1558,18 +1579,22 @@ class BatchingNotaryService(NotaryService):
         flush whatever accumulated during the last delivery round —
         unless a batching deadline is set and neither it nor max_batch
         has been reached yet. Returns requests answered (0 = held or
-        quiescent)."""
+        quiescent).
+
+        Each tick ends held, starved or flushed (`_pump_episode`)."""
+        now = time.perf_counter()
         if self.intent_journal is not None:
             # group-commit the WAL's resolution deletes once per tick
             # (the fsync discipline of the fabric journals): answers
             # buffered since the last tick clear in ONE transaction
             self.intent_journal.flush_resolved()
         if self._shards is not None:
-            return self._tick_sharded()
+            return self._tick_sharded(now)
         self._drain_ingest()
         hb = self._health_heartbeat
         n = len(self._pending)
         if not n:
+            self._pump_episode("starved", now)
             if hb is not None:
                 hb.beat()
             return 0
@@ -1582,15 +1607,40 @@ class BatchingNotaryService(NotaryService):
                 # held, not wedged: the loop is alive (beat), it just
                 # chose to wait — zero progress, which is exactly what
                 # livelock detection should see while a batch forms
+                self._pump_episode("hold", now)
                 if hb is not None:
                     hb.beat()
                 return 0
+        self._pump_episode(None, now)
         self.flush()
         if hb is not None:
             hb.beat(progress=n)
         return n
 
-    def _tick_sharded(self) -> int:
+    def _pump_episode(self, state: Optional[str], now: float) -> None:
+        """The pump's state at a tick: "hold" (work pending below the
+        batch cap, inside the batching deadline), "starved" (nothing
+        drained, nothing pending) or None (the tick flushes). An
+        episode runs from the first tick in a state to the first tick
+        in another (or a flush), and is then charged to
+        Notary.PumpHold / Notary.PumpStarved; while a capture is active
+        it is also the profiler region `notary.hold` / `notary.starved`."""
+        prev = self._pump_state
+        if state == prev:
+            if state is not None and self._pump_region is None:
+                # a capture that started inside the episode
+                self._pump_region = tracing.open_region(_PUMP_REGIONS[state])
+            return
+        if prev is not None:
+            self._pump_timers[prev].update(now - self._pump_since)
+            tracing.close_region(self._pump_region)
+            self._pump_region = None
+        self._pump_state = state
+        self._pump_since = now
+        if state is not None:
+            self._pump_region = tracing.open_region(_PUMP_REGIONS[state])
+
+    def _tick_sharded(self, t_tick: float) -> int:
         """One pump round over the sharded commit plane: route fresh
         ingest arrivals, then flush every shard whose batch is due —
         inline as a dispatch-all-then-consume wave (device compute for
@@ -1600,6 +1650,7 @@ class BatchingNotaryService(NotaryService):
         self._drain_ingest()
         now = self.services.clock.now_micros()
         due: list[_NotaryShard] = []
+        woken = False
         total_backlog = 0
         for shard in self._shards:
             with shard.cond:
@@ -1620,8 +1671,14 @@ class BatchingNotaryService(NotaryService):
                 if self._workers:
                     shard.wake = True
                     shard.cond.notify_all()
+                    woken = True
                 else:
                     due.append(shard)
+        self._pump_episode(
+            None if due or woken
+            else "hold" if total_backlog else "starved",
+            t_tick,
+        )
         answered = self._flush_wave(due) if due else 0
         answered += self._drain_completions()
         if self.qos is not None and hasattr(self.qos, "observe_backlog"):
@@ -1651,7 +1708,11 @@ class BatchingNotaryService(NotaryService):
         return n
 
     def stop(self) -> None:
-        """Stop shard worker threads (no-op without them)."""
+        """Stop shard worker threads and drop this service's hold on
+        the process GC watch."""
+        if self._gc_watched:
+            self._gc_watched = False
+            self._gc_watch.release()
         if not self._workers:
             return
         self._stop_workers = True
@@ -1664,27 +1725,25 @@ class BatchingNotaryService(NotaryService):
         self._drain_completions()
 
     def _mark(
-        self, phase: str, t_prev: float, marks: Optional[list] = None
+        self, phase: str, t_prev: float, marks: Optional[_FlushMarks] = None,
+        then: Optional[str] = None,
     ) -> float:
         """Phase boundary: charge now - t_prev to `phase` on the
-        registry timer (always), the profile dict (when
-        CORDA_TPU_NOTARY_PROFILE is set), and `marks` (the per-flush
-        interval list trace-span emission consumes). Always returns
-        now so call sites stay one-liners."""
+        registry timer (always) and in `marks` (the per-flush interval
+        list trace-span emission consumes), and move the flush's
+        profiler region from `notary.<phase>` to `notary.<then>`, the
+        phase this boundary starts (None: no phase follows). Always
+        returns now so call sites stay one-liners."""
         now = time.perf_counter()
-        dt = now - t_prev
+        if marks is not None:
+            marks.next_region(then)
+            marks.append((phase, t_prev, now))
         timer = self._phase_timers.get(phase)
         if timer is None:
             timer = self._phase_timers[phase] = self.metrics.timer(
                 "Notary.FlushPhase." + phase
             )
-        timer.update(dt)
-        if self._phase_profile is not None:
-            self._phase_profile[phase] = (
-                self._phase_profile.get(phase, 0.0) + dt
-            )
-        if marks is not None:
-            marks.append((phase, t_prev, now))
+        timer.update(now - t_prev)
         return now
 
     def _gc_pause(self) -> None:
@@ -1715,6 +1774,8 @@ class BatchingNotaryService(NotaryService):
         wave, or — with worker threads — by waking every shard and
         blocking until they go idle, then resolving the completions on
         the calling thread (which acts as the pump)."""
+        if self._pump_state is not None:
+            self._pump_episode(None, time.perf_counter())
         if self.intent_journal is not None:
             self.intent_journal.flush_resolved()
         self._drain_ingest()   # pre-ingested arrivals join this flush
@@ -1782,7 +1843,7 @@ class BatchingNotaryService(NotaryService):
                     if not pending:
                         self._shard_done(shard, 0)
                         continue
-                marks: list[tuple[str, float, float]] = []
+                marks = _FlushMarks()
                 ctx = self._stage_and_dispatch(pending, marks, shard)
                 staged.append((shard, pending, marks, ctx))
             for shard, pending, marks, ctx in staged:
@@ -1823,7 +1884,7 @@ class BatchingNotaryService(NotaryService):
                 if not pending:
                     self._shard_done(shard, 0)
                     return 0
-            marks: list[tuple[str, float, float]] = []
+            marks = _FlushMarks()
             try:
                 ctx = self._stage_and_dispatch(pending, marks, shard)
                 if ctx is not None:
@@ -1911,7 +1972,7 @@ class BatchingNotaryService(NotaryService):
         # attributes them to every member frame's trace and ENDS the
         # per-frame root spans — on every exit path (normal, streamed,
         # dispatch failure), so upstream traces always complete
-        marks: list[tuple[str, float, float]] = []
+        marks = _FlushMarks()
         try:
             self._flush_body(pending, marks)
         finally:
@@ -2020,7 +2081,9 @@ class BatchingNotaryService(NotaryService):
         — the perf plane's skew rule — can cite the traces that
         touched the hot shard). Spans are emitted on the tracer that
         OWNS the frame's root span, so mixed tracer setups still
-        assemble whole traces."""
+        assemble whole traces. Every flush path ends here, so the
+        profiler region a failed flush left open closes here too."""
+        marks.next_region(None)
         n = len(pending)
         sid = shard.id if shard is not None else None
         for p in pending:
@@ -2072,6 +2135,7 @@ class BatchingNotaryService(NotaryService):
         _consume_flush, or None when there is nothing left to consume
         (every future already answered)."""
         t = time.perf_counter()
+        marks.next_region("stage")
         # phase 1 — ONE SPI dispatch across all pending transactions.
         # Staging is per-tx-protected: one malformed transaction (bad
         # scheme in signature_requests) must answer ITS future with an
@@ -2102,7 +2166,7 @@ class BatchingNotaryService(NotaryService):
                 [str(p.stx.id) for p in pending],
                 shard=shard.id if shard is not None else None,
             )
-        t = self._mark("stage", t, marks)
+        t = self._mark("stage", t, marks, then="dispatch")
         verifier = (
             shard.verifier
             if shard is not None and shard.verifier is not None
@@ -2114,17 +2178,11 @@ class BatchingNotaryService(NotaryService):
             box: dict = {}
             handle = None
             results = None
-            # TraceAnnotation (when jax provides it): the dispatch span
-            # becomes a named region in an XLA profiler capture, so
-            # host-side traces line up with the device timeline
             try:
-                with tracing.annotate(
-                    "corda_tpu.notary.batch_verify_dispatch"
-                ):
-                    if hasattr(verifier, "verify_batch_async"):
-                        handle = verifier.verify_batch_async(reqs)
-                    else:
-                        results = verifier.verify_batch(reqs)
+                if hasattr(verifier, "verify_batch_async"):
+                    handle = verifier.verify_batch_async(reqs)
+                else:
+                    results = verifier.verify_batch(reqs)
                 if self._degraded and results is not None:
                     # the recovery probe: a degraded notary keeps
                     # attempting the device each flush — one success
@@ -2183,7 +2241,7 @@ class BatchingNotaryService(NotaryService):
                     target=_collect, name="notary-collect", daemon=True
                 )
                 collector.start()
-            t = self._mark("dispatch", t, marks)
+            t = self._mark("dispatch", t, marks, then="resolve_verify")
         except Exception as e:
             # a failed dispatch (unsupported scheme in the batch, device
             # unavailable) must answer every waiting requester, not
@@ -2357,7 +2415,10 @@ class BatchingNotaryService(NotaryService):
                 [p.stx for p in pending],
                 spi=tv if tv_sync else None,
             )
-            t = self._mark("resolve_verify", t, marks)
+            t = self._mark(
+                "resolve_verify", t, marks,
+                then="stream_commit" if stream_ok else "link_wait",
+            )
             if stream_ok:
                 self._stream_tail(
                     pending, spans, contract_errs, deferred_ltx,
@@ -2374,7 +2435,7 @@ class BatchingNotaryService(NotaryService):
                     # async probe success: the handle's results really
                     # came back from the device — NOW it has recovered
                     self._exit_degraded()
-            t = self._mark("link_wait", t, marks)
+            t = self._mark("link_wait", t, marks, then="validate")
         except Exception as e:
             # the device batch died AFTER dispatch (collector fetch /
             # link failure): same degraded seam as the dispatch guard,
@@ -2393,7 +2454,7 @@ class BatchingNotaryService(NotaryService):
                         pending, spans, ctx["reqs"], e
                     )
                     poison = poison | late_poison
-                    t = self._mark("link_wait", t, marks)
+                    t = self._mark("link_wait", t, marks, then="validate")
                 except Exception as e2:   # noqa: BLE001 - answer, not strand
                     for p in pending:
                         p.future.set_result(
@@ -2441,7 +2502,12 @@ class BatchingNotaryService(NotaryService):
                     )
                     continue
             eligible.append(p)
-        t = self._mark("validate", t, marks)
+        synchronous = getattr(self.uniqueness, "batch_synchronous", False)
+        t = self._mark(
+            "validate", t, marks,
+            then=None if not eligible
+            else "commit" if synchronous else "sign_scatter",
+        )
         if not eligible:
             return
         conflict_error = self._conflict_error
@@ -2451,7 +2517,7 @@ class BatchingNotaryService(NotaryService):
         # WHOLE flush through one commit_many (one lock/DB transaction,
         # no future+callback per tx); a distributed provider keeps the
         # per-tx future path since each commit resolves on consensus.
-        if getattr(self.uniqueness, "batch_synchronous", False):
+        if synchronous:
             try:
                 outcomes = self.uniqueness.commit_many(
                     [
@@ -2479,7 +2545,7 @@ class BatchingNotaryService(NotaryService):
                     p.future.set_result(
                         NotaryError("commit-unavailable", str(err))
                     )
-            t = self._mark("commit", t, marks)
+            t = self._mark("commit", t, marks, then="sign_scatter")
             finalize(committed)
             self._mark("sign_scatter", t, marks)
             return
@@ -2677,7 +2743,7 @@ class BatchingNotaryService(NotaryService):
                         NotaryError("verification-unavailable", str(e))
                     )
                 return
-        t = self._mark("stream_commit", t, marks)
+        t = self._mark("stream_commit", t, marks, then="sign_scatter")
         self._finalize_sign(committed)
         self._mark("sign_scatter", t, marks)
 

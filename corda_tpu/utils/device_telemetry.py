@@ -123,6 +123,7 @@ class DeviceAccounting:
             row = self._devices[int(device_id)] = {
                 "dispatches": 0,
                 "requests": 0,
+                "rows": 0,
                 "busy_seconds": 0.0,
                 "queue_wait_seconds": 0.0,
                 "transfer_bytes": 0,
@@ -136,15 +137,18 @@ class DeviceAccounting:
         n: int,
         seconds: float,
         queue_wait_seconds: float = 0.0,
+        rows: Optional[int] = None,
     ) -> None:
         """One kernel launch on one device: `n` real (unpadded)
-        requests, `seconds` of host dispatch wall (the busy proxy the
+        requests in `rows` padded rows (the launch's batch; `n` when
+        omitted), `seconds` of host dispatch wall (the busy proxy the
         window turns into a busy fraction), and the host-side queue
         wait this chunk paid before its launch."""
         with self._lock:
             row = self._row(device_id)
             row["dispatches"] += 1
             row["requests"] += int(n)
+            row["rows"] += int(n if rows is None else rows)
             row["busy_seconds"] += float(seconds)
             row["queue_wait_seconds"] += float(queue_wait_seconds)
 
@@ -168,6 +172,7 @@ class DeviceAccounting:
         totals = {
             "dispatches": sum(r["dispatches"] for r in devices.values()),
             "requests": sum(r["requests"] for r in devices.values()),
+            "rows": sum(r["rows"] for r in devices.values()),
             "busy_seconds": sum(r["busy_seconds"] for r in devices.values()),
             "transfer_bytes": sum(
                 r["transfer_bytes"] for r in devices.values()

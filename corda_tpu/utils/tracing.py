@@ -24,9 +24,11 @@ EVERY batch, not just in one-off profile runs:
                     loadable by chrome://tracing / Perfetto; the node
                     webserver serves it at GET /traces next to
                     /metrics.
-  annotate(name)  — `jax.profiler.TraceAnnotation` when jax provides
-                    it (so host spans line up with XLA device traces in
-                    a profiler capture), a null context otherwise.
+  annotate(name)  — a profiler region (jaxlib `TraceMe`) while a
+                    capture is active, so host work lines up with the
+                    device trace on one clock; a shared null context
+                    otherwise. `open_region`/`close_region` bound one
+                    region by two boundaries on the same thread.
 
 Propagation: `Span.context` is a (trace_id, span_id) pair that rides
 as an optional message header across the MessagingService fabric
@@ -41,6 +43,7 @@ disabled otherwise), or construct/set an explicit `Tracer`.
 
 from __future__ import annotations
 
+import contextlib
 import heapq
 import os
 import random
@@ -846,30 +849,58 @@ def phase_summary(spans: list[dict]) -> dict:
     return out
 
 
-# -- XLA profiler alignment ---------------------------------------------------
+# -- profiler regions ---------------------------------------------------------
+#
+# A region is a `TraceMe` recorded by the profiler itself, so it lands
+# on the same clock as the device trace of the capture. Off a capture
+# nothing is built: `annotate` hands back one shared null context and
+# `open_region` returns None.
 
-_annotation_cls: Any = None
+_traceme: Any = None
+_NULL = contextlib.nullcontext()
 
 
-def annotate(name: str):
-    """`jax.profiler.TraceAnnotation(name)` when available — a span
-    wrapped in this shows up as a named region in an XLA profiler
-    capture, lining host spans up with device timelines — else a null
-    context. The import resolves once and never at module import (this
-    module must stay loadable without jax)."""
-    global _annotation_cls
-    if _annotation_cls is None:
+def _traceme_cls():
+    """jaxlib's TraceMe, resolved once on first use and never at module
+    import (this module must stay loadable without jax); False when
+    jaxlib is absent."""
+    global _traceme
+    if _traceme is None:
         try:
-            from jax.profiler import TraceAnnotation
+            from jaxlib._profiler import TraceMe
+        except Exception:   # jaxlib absent or too old: permanent null
+            TraceMe = False
+        _traceme = TraceMe
+    return _traceme
 
-            _annotation_cls = TraceAnnotation
-        except Exception:   # jax absent or too old: permanent null
-            _annotation_cls = False
-    if _annotation_cls:
-        return _annotation_cls(name)
-    import contextlib
 
-    return contextlib.nullcontext()
+def annotate(name: str, **metadata):
+    """A profiler region over a `with` block, carrying `metadata` as
+    its arguments; the shared null context when no capture is active."""
+    cls = _traceme_cls()
+    if cls and cls.is_enabled():
+        return cls(name, **metadata)
+    return _NULL
+
+
+def open_region(name: str, **metadata):
+    """Open a profiler region at one boundary, to be closed by
+    `close_region` at a later one on the same thread. None (and
+    nothing built) when no capture is active."""
+    region = annotate(name, **metadata)
+    if region is _NULL:
+        return None
+    region.__enter__()
+    return region
+
+
+def close_region(region, **metadata) -> None:
+    """Close a region from `open_region`, adding `metadata` to it; None
+    is a no-op."""
+    if region is not None:
+        if metadata:
+            region.set_metadata(**metadata)
+        region.__exit__(None, None, None)
 
 
 # -- process default ----------------------------------------------------------

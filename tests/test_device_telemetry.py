@@ -248,6 +248,24 @@ def test_unpinned_dispatch_times_the_device_put_transfer(monkeypatch):
     assert snap["totals"]["transfer_seconds"] > 0
 
 
+def test_dispatch_records_real_and_padded_rows(monkeypatch):
+    """A launch records the real rows it verifies and the padded rows
+    the ladder computes for them (its batch), side by side."""
+    _stub_kernels(monkeypatch)
+    devacct = dlib.DeviceAccounting()
+    dlib.set_device_accounting(devacct)
+    try:
+        v = TpuBatchVerifier(batch_sizes=(32,))
+        assert all(v.verify_batch(_p256_requests(5)))
+    finally:
+        dlib.set_device_accounting(None)
+    totals = devacct.snapshot()["totals"]
+    assert (totals["dispatches"], totals["requests"], totals["rows"]) == (
+        1, 5, 32)
+    devacct.record_dispatch(0, 7, 0.001)      # rows default to the real
+    assert devacct.snapshot()["totals"]["rows"] == 39
+
+
 def test_multi_device_dispatch_attribution(monkeypatch):
     """The kernel-stubbed multi-device rig: two device-pinned
     verifiers (the sharded notary's per-device path) attribute busy

@@ -77,7 +77,13 @@ def test_ladder_compiles_for_v5e(name, one_chip, no_persistent_cache):
     limbs = jax.ShapeDtypeStruct((22, BATCH), jnp.int32, sharding=one_chip)
     fn = partial(LADDERS[name], block=BLOCK, limbs=1)
     compiled = jax.jit(fn).lower(limbs, limbs, limbs, limbs).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the kernel carries its ladder's name into the HLO, and so into a
+    # profiler trace of the chip
+    curve, variant = name.split("-")
+    kind = "windowed" if variant == "windowed" else "bit"
+    assert f"%ladder_{curve}_{kind}" in text
 
 
 def test_odd_batch_pads_to_lane_tiles(one_chip, no_persistent_cache):
