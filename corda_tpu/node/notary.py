@@ -1053,9 +1053,10 @@ class BatchingNotaryService(NotaryService):
         self._pump_state: Optional[str] = None
         self._pump_since = 0.0
         self._pump_region = None
-        # the process's collector pauses, on this registry's /metrics
+        # the process's collector pauses, on this registry's /metrics,
+        # with the full passes paced by their own cost while this serves
         self._gc_watch = runtime.get_gc_watch()
-        self._gc_watch.acquire()
+        self._gc_watch.acquire(pace=True)
         self._gc_watched = True
         runtime.register_gc_gauges(self.metrics)
         # -- fault-tolerance plane (round 9) ----------------------------
@@ -1708,11 +1709,11 @@ class BatchingNotaryService(NotaryService):
         return n
 
     def stop(self) -> None:
-        """Stop shard worker threads and drop this service's hold on
-        the process GC watch."""
+        """Stop shard worker threads and drop this service's pacing
+        hold on the process GC watch."""
         if self._gc_watched:
             self._gc_watched = False
-            self._gc_watch.release()
+            self._gc_watch.release(pace=True)
         if not self._workers:
             return
         self._stop_workers = True

@@ -112,7 +112,7 @@ class AppendEntries:
     prev_log_index: int
     prev_log_term: int
     # (term, command) pairs; a TRACED entry ships as a
-    # (term, command, wire_trace_header) triple so a 64-entry batch
+    # (term, command, (trace_id, span_id)) triple so a 64-entry batch
     # attributes each entry to ITS OWN client trace (one message-level
     # header could not say which entry it belongs to). The header is
     # observability metadata: receivers strip it before the log append,
@@ -512,11 +512,14 @@ class RaftNode:
             )
 
     def _bind_trace(self, idx: int, hdr) -> None:
-        if hdr is None:
+        # bound as the bare (trace_id, span_id) pair each entry ships
+        # with; a malformed wire header binds nothing
+        ctx = tracing.SpanContext.from_header(hdr)
+        if ctx is None:
             return
         if len(self._entry_trace) >= _TRACE_TABLE_CAP:
             self._entry_trace.pop(next(iter(self._entry_trace)))
-        self._entry_trace[idx] = tuple(hdr)
+        self._entry_trace[idx] = (ctx[0], ctx[1])
 
     def _bind_t0(self, idx: int) -> None:
         if not self._observing():
@@ -698,15 +701,14 @@ class RaftNode:
         if self._entry_trace:
             entries = []
             for k, (t, c) in enumerate(window):
-                hdr = self._entry_trace.get(prev + 1 + k)
-                if hdr is not None:
-                    hdr = tracing.wire_trace(hdr)
+                ctx = self._entry_trace.get(prev + 1 + k)
+                if ctx is not None:
                     if msg_hdr is None:
                         # message-level header: the first traced
-                        # entry's context — what feeds the receiver's
-                        # clock-offset evidence
-                        msg_hdr = hdr
-                    entries.append((t, c, hdr))
+                        # entry's context with the send stamp — what
+                        # feeds the receiver's clock-offset evidence
+                        msg_hdr = tracing.wire_trace(ctx)
+                    entries.append((t, c, ctx))
                 else:
                     entries.append((t, c))
             entries = tuple(entries)
@@ -872,7 +874,7 @@ class RaftNode:
             # per-entry header, named apart from the MESSAGE-level
             # `hdr` parameter (the first traced entry's context, which
             # the batch append span below is stamped into)
-            e_hdr = tuple(entry[2]) if len(entry) > 2 and entry[2] else None
+            e_hdr = entry[2] if len(entry) > 2 and entry[2] else None
             if idx <= self.last_log_index:
                 if self._term_at(idx) == term:
                     # term-matched redelivery: bind the header if the

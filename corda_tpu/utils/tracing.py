@@ -43,6 +43,7 @@ disabled otherwise), or construct/set an explicit `Tracer`.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import heapq
 import os
@@ -82,7 +83,7 @@ class SpanContext(tuple):
         if header is None:
             return None
         try:
-            return cls(int(header[0]), int(header[1]))
+            return tuple.__new__(cls, (int(header[0]), int(header[1])))
         except Exception:
             return None
 
@@ -102,7 +103,7 @@ def wire_trace(parent) -> Optional[tuple]:
         else SpanContext.from_header(parent)
     if ctx is None:
         return None
-    return (ctx.trace_id, ctx.span_id, int(time.perf_counter() * 1e6))
+    return (ctx[0], ctx[1], int(time.perf_counter() * 1e6))
 
 
 class ClockSync:
@@ -126,7 +127,9 @@ class ClockSync:
     def observe(self, peer: str, sent_us, recv_us: Optional[int] = None) -> None:
         if recv_us is None:
             recv_us = int(time.perf_counter() * 1e6)
-        skew = int(recv_us) - int(sent_us)
+        self._record(peer, int(recv_us) - int(sent_us))
+
+    def _record(self, peer: str, skew: int) -> None:
         with self._lock:
             row = self._obs.get(peer)
             if row is None:
@@ -141,9 +144,10 @@ class ClockSync:
         send timestamp (3rd element); no-op otherwise."""
         if header is not None and len(header) >= 3:
             try:
-                self.observe(peer, int(header[2]))
+                sent_us = int(header[2])
             except (TypeError, ValueError):
-                pass
+                return
+            self._record(peer, int(time.perf_counter() * 1e6) - sent_us)
 
     def min_skew(self, peer: str) -> Optional[int]:
         with self._lock:
@@ -333,7 +337,9 @@ class FlightRecorder:
         self.keep_recent = max(1, keep_recent)
         self.keep_slowest = max(1, keep_slowest)
         self._lock = locks.make_lock("FlightRecorder._lock")
-        self._recent: list[Trace] = []
+        self._recent: collections.deque[Trace] = collections.deque(
+            maxlen=self.keep_recent
+        )
         self._slow: list[tuple[float, int, Trace]] = []   # min-heap
         self._seq = 0
         self.recorded = 0   # lifetime total, for the /traces summary
@@ -343,8 +349,6 @@ class FlightRecorder:
             self.recorded += 1
             self._seq += 1
             self._recent.append(trace)
-            if len(self._recent) > self.keep_recent:
-                del self._recent[0]
             entry = (trace.duration_s, self._seq, trace)
             if len(self._slow) < self.keep_slowest:
                 heapq.heappush(self._slow, entry)
@@ -448,11 +452,30 @@ class Tracer:
         """A pre-timed, immediately-completed child span: batch stages
         (one decode pass over 512 frames) measure ONE interval and
         attribute it to every member frame's trace without holding 512
-        live spans open."""
-        span = self.start_span(name, parent, **attributes)
-        if span:
-            span.start = start
-            span.end(end)
+        live spans open. A span whose trace is not open here completes
+        as a one-span trace without touching the open table."""
+        if not self.enabled:
+            return NOOP_SPAN
+        ctx = parent.context if isinstance(parent, (Span, _NoopSpan)) \
+            else SpanContext.from_header(parent)
+        if ctx is None:
+            return NOOP_SPAN
+        trace_id = ctx[0]
+        done: Optional[Trace] = None
+        with self._lock:
+            self._next_span += 1
+            span = Span(
+                self, name, trace_id, self._span_salt + self._next_span,
+                ctx[1], start, attributes or None,
+            )
+            span.end_time = end
+            state = self._open.get(trace_id)
+            if state is not None:
+                state[0].append(span)
+            else:
+                done = Trace(trace_id, [span])
+        if done is not None and self.recorder is not None:
+            self.recorder.record(done)
         return span
 
     # -- assembly -----------------------------------------------------------

@@ -147,6 +147,45 @@ def test_raft_phase_spans_join_client_trace_across_members():
                 assert isinstance(e["args"]["at"], int)
 
 
+def test_raft_append_frames_carry_entry_contexts_and_one_send_stamp():
+    """A traced entry ships in AppendEntries as a bare (trace_id,
+    span_id) pair; the frame's own header is the first traced entry's
+    context plus the sender's send stamp (the clock-offset evidence).
+    A malformed header binds nothing."""
+    from corda_tpu.core import serialization as ser
+    from corda_tpu.node.raft import LEADER, AppendEntries
+
+    net, members, tracers, _ = make_traced_raft_cluster(seed=7)
+    leader = next(m for m in members if m.raft.role == LEADER)
+    sent = []
+    send = leader.raft._send
+
+    def spy(peer, message, trace=None):
+        sent.append((message, trace))
+        send(peer, message, trace=trace)
+
+    leader.raft._send = spy
+    fut, root = commit_traced(net, leader, tracers, "frame-shape")
+    assert fut.result() is None
+    ctx = (root.trace_id, root.span_id)
+    traced = [
+        (m, hdr) for m, hdr in sent
+        if isinstance(m, AppendEntries) and any(len(e) > 2 for e in m.entries)
+    ]
+    assert traced, "no AppendEntries frame carried the traced entry"
+    for m, hdr in traced:
+        heads = [tuple(e[2]) for e in m.entries if len(e) > 2]
+        assert heads == [ctx]
+        assert hdr[:2] == ctx and len(hdr) == 3 and isinstance(hdr[2], int)
+        # the pair survives the codec as the follower decodes it
+        entry = ser.decode(ser.encode(m)).entries[-1]
+        assert tuple(entry[2]) == ctx
+    leader.raft._bind_trace(10_000, "garbage")
+    leader.raft._bind_trace(10_001, None)
+    assert 10_000 not in leader.raft._entry_trace
+    assert 10_001 not in leader.raft._entry_trace
+
+
 def test_raft_phase_timers_and_lag_gauges_always_on():
     """Raft.Phase.* timers count phases with tracing OFF too, and the
     quorum-lag gauges render on the exposition."""

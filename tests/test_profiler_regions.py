@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import gc
 import glob
+import math
 import os
 import time
 
@@ -300,7 +301,8 @@ def test_gc_pauses_are_regions_and_gauges(tmp_path):
         assert metrics.get("Runtime.GcSeconds.gen2").value() > 0
     finally:
         watch.release()
-    assert any(st["generation"] == 2 for _, _, st in regions["gc.collect"])
+    full = [st for _, _, st in regions["gc.collect"] if st["generation"] == 2]
+    assert full and all(st["threshold"] >= 1 for st in full)
 
 
 def test_notary_holds_the_watch():
@@ -310,6 +312,110 @@ def test_notary_holds_the_watch():
     assert "Runtime.GcSeconds.gen2" in svc.metrics.names()
     svc.stop()
     svc.stop()                          # idempotent: one release
+
+
+# -- pacing the full passes ---------------------------------------------------
+
+
+@pytest.mark.parametrize("rate1,pass_s,floor,want", [
+    (8.0, 0.002, 10, 10),               # ms-scale passes: CPython's 10
+    (8.0, 0.002, 25, 25),               # never below the prior threshold
+    (0.5, 5.0, 10, math.ceil(0.5 * runtime.FULL_PASS_MAX_S)),  # T_MAX
+    (7.3, 0.41, 10, math.ceil(7.3 * 0.41 * 19)),               # between
+])
+def test_full_pass_threshold_rule(rate1, pass_s, floor, want):
+    assert runtime.FULL_PASS_SHARE == 0.05
+    assert runtime.full_pass_threshold(rate1, pass_s, floor) == want
+
+
+def test_pacing_hold_is_refcounted_and_restores_thresholds():
+    watch = runtime.GcWatch()
+    callbacks = list(gc.callbacks)
+    prior = gc.get_threshold()
+    enabled = gc.isenabled()
+    gc.disable()
+    try:
+        watch.acquire(pace=True)
+        watch.acquire(pace=True)        # two holders, one install
+        assert gc.callbacks == callbacks + [watch._callback]
+        watch.release(pace=True)
+        assert watch._callback in gc.callbacks
+        watch.release(pace=True)
+        assert gc.callbacks == callbacks
+        watch.acquire()
+        watch.release(pace=True)        # a plain hold is not a pace hold
+        assert watch._refs == 1
+        watch.release()
+        watch.acquire(pace=True)
+        watch.acquire(pace=True)
+        gc.set_threshold(prior[0], prior[1], 77)    # as the pacer would
+        watch.release(pace=True)
+        assert gc.get_threshold()[2] == 77 and watch._paced == 1
+        watch.release(pace=True)        # the last: the prior, exactly
+        assert gc.get_threshold() == prior
+        watch.release(pace=True)        # one too many: a no-op
+        watch.release()
+        assert gc.get_threshold() == prior and gc.callbacks == callbacks
+    finally:
+        gc.set_threshold(*prior)
+        if enabled:
+            gc.enable()
+
+
+def test_cycles_are_still_collected_while_paced():
+    watch = runtime.GcWatch()
+    prior = gc.get_threshold()
+    enabled = gc.isenabled()
+    # the test process's heap out of the way: the 25% rule then weighs
+    # the objects below alone
+    gc.freeze()
+    gc.enable()
+    watch.acquire(pace=True)
+    try:
+        gc.collect(2)                   # gen-1 count from zero
+        n1 = watch.collections[1]
+        # a 1-s pass at 2 gen-1 collections per second: 38 between passes
+        watch._full_mark = (time.perf_counter() - 10.0, n1 - 20)
+        watch._pace(time.perf_counter(), 1.0)
+        assert gc.get_threshold()[2] == math.ceil(2 * 1.0 * 19) == 38
+        cycles = [[] for _ in range(10_000)]
+        for c in cycles:
+            c.append(c)
+        gc.collect(1)                   # promoted: only a full pass frees them
+        del cycles, c
+        collected = gc.get_stats()[2]["collected"]
+        n2 = watch.collections[2]
+        keep: list = []
+        for k in range(5_000_000):
+            keep.append([k])            # survivors, so the counts advance
+            if len(keep) > 20_000:
+                del keep[:10_000]
+            if watch.collections[2] > n2:
+                break
+        assert watch.collections[2] == n2 + 1
+        assert watch.collections[1] - n1 >= 38
+        assert gc.get_stats()[2]["collected"] >= collected + 10_000
+    finally:
+        watch.release(pace=True)
+        gc.unfreeze()
+        if not enabled:
+            gc.disable()
+    assert gc.get_threshold() == prior
+
+
+def test_notary_engages_the_pacer_until_stop():
+    watch = runtime.get_gc_watch()
+    paced = watch._paced
+    net, notary, svc = _notary()
+    assert watch._paced == paced + 1
+    assert svc.metrics.get("Runtime.GcFullThreshold").value() == (
+        gc.get_threshold()[2])
+    svc.stop()
+    svc.stop()                          # idempotent: one release
+    assert watch._paced == paced
+    for validating in (False, True):    # simple and validating: never
+        MockNetwork(seed=24).create_notary("Plain", validating=validating)
+        assert watch._paced == paced
 
 
 # -- intake -------------------------------------------------------------------

@@ -141,6 +141,31 @@ def test_flight_recorder_keeps_slowest_under_churn():
     assert rec.recorded == 203
 
 
+def test_span_at_joins_an_open_trace_or_completes_alone():
+    """A pre-timed span joins a trace still open on this tracer (the
+    trace records when its last span ends), and under a propagated
+    header whose trace is not open here it records at once as a
+    one-span trace, leaving the open table empty."""
+    tracer = Tracer(enabled=True)
+    root = tracer.start_trace("notarise.frame")
+    stage = tracer.span_at("stage", root, 1.0, 2.5, batch=3)
+    assert (stage.start, stage.end_time) == (1.0, 2.5)
+    assert stage.parent_id == root.span_id
+    assert stage.attributes == {"batch": 3}
+    assert tracer.recorder.traces() == []
+    root.end()
+    (joined,) = tracer.recorder.traces()
+    assert {s.name for s in joined.spans} == {"notarise.frame", "stage"}
+
+    remote = tracer.span_at("raft.apply", (77 << 20, 5, 123), 4.0, 4.25)
+    assert (remote.trace_id, remote.parent_id) == (77 << 20, 5)
+    assert tracer._open == {}
+    alone = [t for t in tracer.recorder.traces() if t.trace_id == 77 << 20]
+    assert len(alone) == 1 and alone[0].spans == [remote]
+    assert alone[0].duration_s == 0.25
+    assert tracer.span_at("x", "garbage", 0.0, 1.0) is NOOP_SPAN
+
+
 def test_chrome_export_roundtrips_json():
     tracer = Tracer(enabled=True)
     root = tracer.start_trace("notarise.frame", wire_bytes=123)
