@@ -891,19 +891,21 @@ _PUMP_REGIONS = {"hold": "notary.hold", "starved": "notary.starved"}
 class _FlushMarks(list):
     """One flush's phase intervals [(phase, t0, t1)] in mark order,
     and the profiler region open over the phase in progress (None off
-    a capture)."""
+    a capture). A sharded flush's regions carry its `shard` id."""
 
-    __slots__ = ("region",)
+    __slots__ = ("region", "args")
 
-    def __init__(self):
+    def __init__(self, shard: Optional[int] = None):
         super().__init__()
         self.region = None
+        self.args = {} if shard is None else {"shard": shard}
 
     def next_region(self, phase: Optional[str]) -> None:
         """Close the open region; open `notary.<phase>` (None: none)."""
         tracing.close_region(self.region)
         self.region = (
-            tracing.open_region("notary." + phase) if phase else None
+            tracing.open_region("notary." + phase, **self.args)
+            if phase else None
         )
 
 
@@ -1645,12 +1647,14 @@ class BatchingNotaryService(NotaryService):
         """One pump round over the sharded commit plane: route fresh
         ingest arrivals, then flush every shard whose batch is due —
         inline as a dispatch-all-then-consume wave (device compute for
-        shard k overlaps host work for shard j), or by waking each due
-        shard's worker thread. Completions from worker flushes resolve
+        shard k overlaps host work for shard j) that also takes every
+        other shard with work, or by waking each due shard's worker
+        thread. Completions from worker flushes resolve
         HERE, on the pump thread."""
         self._drain_ingest()
         now = self.services.clock.now_micros()
         due: list[_NotaryShard] = []
+        held: list[_NotaryShard] = []
         woken = False
         total_backlog = 0
         for shard in self._shards:
@@ -1668,6 +1672,7 @@ class BatchingNotaryService(NotaryService):
                         # held, not wedged (see the unsharded tick)
                         if shard.heartbeat is not None:
                             shard.heartbeat.beat()
+                        held.append(shard)
                         continue
                 if self._workers:
                     shard.wake = True
@@ -1675,6 +1680,12 @@ class BatchingNotaryService(NotaryService):
                     woken = True
                 else:
                     due.append(shard)
+        if due and held:
+            # a wave carries every shard with work: a shard held back
+            # would fall due inside the wave and take the next one
+            # alone, and the shards' batches would drift apart into
+            # back-to-back waves of a few shards each
+            due = sorted(due + held, key=lambda s: s.id)
         self._pump_episode(
             None if due or woken
             else "hold" if total_backlog else "starved",
@@ -1828,10 +1839,16 @@ class BatchingNotaryService(NotaryService):
         shard's verify batch (per-device, async), phase B consumes them
         in shard order — so while shard k's host validate/commit runs,
         shards k+1..N's device compute is already in flight. One GC
-        pause spans the wave."""
+        pause spans the wave.
+
+        Under a capture the wave is the region `notary.wave`, with the
+        shards it flushed, the plane's shard count (`n_shards`), the
+        transactions it carried (`frames`) and the deepest shard's
+        count (`max_frames`)."""
         if not shards:
             return 0
-        total = 0
+        depths: list[int] = []
+        region = tracing.open_region("notary.wave")
         self._gc_pause()
         try:
             staged = []
@@ -1844,7 +1861,7 @@ class BatchingNotaryService(NotaryService):
                     if not pending:
                         self._shard_done(shard, 0)
                         continue
-                marks = _FlushMarks()
+                marks = _FlushMarks(shard.id)
                 ctx = self._stage_and_dispatch(pending, marks, shard)
                 staged.append((shard, pending, marks, ctx))
             for shard, pending, marks, ctx in staged:
@@ -1856,7 +1873,7 @@ class BatchingNotaryService(NotaryService):
                     if self.qos is not None:
                         self._qos_feedback(pending, shard)
                     self._shard_done(shard, len(pending))
-                total += len(pending)
+                depths.append(len(pending))
             if self._perf is not None and staged:
                 # one wave observation: per-shard skew feeds plus the
                 # dispatch-vs-consume overlap efficiency (the wave's
@@ -1870,7 +1887,12 @@ class BatchingNotaryService(NotaryService):
                 )
         finally:
             self._gc_resume()
-        return total
+            if region is not None:
+                tracing.close_region(
+                    region, shards=len(depths), n_shards=self.n_shards,
+                    frames=sum(depths), max_frames=max(depths, default=0),
+                )
+        return sum(depths)
 
     def _flush_one_shard(self, shard) -> int:
         """Full flush pipeline for ONE shard (worker threads; also the
@@ -1885,7 +1907,7 @@ class BatchingNotaryService(NotaryService):
                 if not pending:
                     self._shard_done(shard, 0)
                     return 0
-            marks = _FlushMarks()
+            marks = _FlushMarks(shard.id)
             try:
                 ctx = self._stage_and_dispatch(pending, marks, shard)
                 if ctx is not None:
@@ -2242,7 +2264,7 @@ class BatchingNotaryService(NotaryService):
                     target=_collect, name="notary-collect", daemon=True
                 )
                 collector.start()
-            t = self._mark("dispatch", t, marks, then="resolve_verify")
+            self._mark("dispatch", t, marks)
         except Exception as e:
             # a failed dispatch (unsupported scheme in the batch, device
             # unavailable) must answer every waiting requester, not
@@ -2260,7 +2282,6 @@ class BatchingNotaryService(NotaryService):
             "collector": collector,
             "box": box,
             "stream_ok": stream_ok,
-            "t": t,
             "reqs": reqs,
             "poison": poison,
         }
@@ -2375,7 +2396,10 @@ class BatchingNotaryService(NotaryService):
         commit against the (possibly partitioned) uniqueness provider,
         sign and scatter replies. Runs while OTHER shards' device
         batches are still computing — that overlap is the sharded
-        plane's wave pipeline."""
+        plane's wave pipeline. resolve_verify starts here, so in a wave
+        it leaves out the other shards' stage, dispatch and consume."""
+        marks.next_region("resolve_verify")
+        t = time.perf_counter()
         pending = ctx["pending"]
         spans = ctx["spans"]
         handle = ctx["handle"]
@@ -2383,7 +2407,6 @@ class BatchingNotaryService(NotaryService):
         collector = ctx["collector"]
         box = ctx["box"]
         stream_ok = ctx["stream_ok"]
-        t = ctx["t"]
         poison = ctx.get("poison") or set()
         contract_errs = deferred_ltx = None
         try:
