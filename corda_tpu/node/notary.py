@@ -1056,7 +1056,7 @@ class BatchingNotaryService(NotaryService):
         self._pump_since = 0.0
         self._pump_region = None
         # the process's collector pauses, on this registry's /metrics,
-        # with the full passes paced by their own cost while this serves
+        # with its passes paced by their own cost while this serves
         self._gc_watch = runtime.get_gc_watch()
         self._gc_watch.acquire(pace=True)
         self._gc_watched = True

@@ -296,13 +296,17 @@ def test_gc_pauses_are_regions_and_gauges(tmp_path):
     watch.acquire()
     try:
         n2 = metrics.get("Runtime.GcCollections.gen2").value()
-        regions = _capture(tmp_path, lambda: gc.collect(2))
+        regions = _capture(tmp_path, lambda: (gc.collect(0), gc.collect(2)))
         assert metrics.get("Runtime.GcCollections.gen2").value() >= n2 + 1
         assert metrics.get("Runtime.GcSeconds.gen2").value() > 0
+        assert metrics.get("Runtime.GcYoungThreshold").value() == (
+            gc.get_threshold()[0])
     finally:
         watch.release()
-    full = [st for _, _, st in regions["gc.collect"] if st["generation"] == 2]
-    assert full and all(st["threshold"] >= 1 for st in full)
+    for gen in (0, 2):
+        got = [st for _, _, st in regions["gc.collect"]
+               if st["generation"] == gen]
+        assert got and all(st["threshold"] >= 1 for st in got), gen
 
 
 def test_notary_holds_the_watch():
@@ -314,7 +318,7 @@ def test_notary_holds_the_watch():
     svc.stop()                          # idempotent: one release
 
 
-# -- pacing the full passes ---------------------------------------------------
+# -- pacing the full and young passes ----------------------------------------
 
 
 @pytest.mark.parametrize("rate1,pass_s,floor,want", [
@@ -385,16 +389,117 @@ def test_cycles_are_still_collected_while_paced():
         del cycles, c
         collected = gc.get_stats()[2]["collected"]
         n2 = watch.collections[2]
+        young = watch.collections[0] + watch.collections[1]
+        widest = 0
         keep: list = []
-        for k in range(5_000_000):
-            keep.append([k])            # survivors, so the counts advance
-            if len(keep) > 20_000:
-                del keep[:10_000]
+        for k in range(60_000_000):
+            # survivors that live until the next young pass, as a
+            # serving notary's in-flight frames do: the counts advance
+            keep.append([k])
+            if watch.collections[0] + watch.collections[1] > young:
+                young = watch.collections[0] + watch.collections[1]
+                widest = max(widest, len(keep))
+                keep = []
             if watch.collections[2] > n2:
                 break
+        # both pacers ran: young passes spaced above the floor, within
+        # the cap, and the full pass after the full pacer's 38 gen-1
+        # passes (every second young pass once threshold0 is wide)
+        assert prior[0] < widest <= runtime.YOUNG_PASS_MAX_N + 1_000
         assert watch.collections[2] == n2 + 1
         assert watch.collections[1] - n1 >= 38
         assert gc.get_stats()[2]["collected"] >= collected + 10_000
+    finally:
+        watch.release(pace=True)
+        gc.unfreeze()
+        if not enabled:
+            gc.disable()
+    assert gc.get_threshold() == prior
+
+
+@pytest.mark.parametrize("rate0,pass_s,floor,want", [
+    (20_000.0, 0.0005, 700, 700),       # short passes: CPython's 700
+    (20_000.0, 0.0005, 2_000, 2_000),   # never below the prior threshold
+    (30_000.0, 1.0, 700,                # YOUNG_PASS_MAX_S of allocations
+     math.ceil(30_000 * runtime.YOUNG_PASS_MAX_S)),
+    (250_000.0, 0.004, 700, math.ceil(250_000 * 0.004 * 19)),  # between
+    (250_000.0, 1.0, 700, runtime.YOUNG_PASS_MAX_N),  # a long pass: N
+    (2e7, 0.001, 700, runtime.YOUNG_PASS_MAX_N),      # a burst's rate: N
+])
+def test_young_pass_threshold_rule(rate0, pass_s, floor, want):
+    assert runtime.YOUNG_PASS_SHARE == 0.05
+    assert runtime.young_pass_threshold(rate0, pass_s, floor) == want
+
+
+def test_paced_young_pass_raises_threshold0_until_the_last_release():
+    watch = runtime.GcWatch()
+    callbacks = list(gc.callbacks)
+    prior = gc.get_threshold()
+    enabled = gc.isenabled()
+    gc.disable()
+    try:
+        watch.acquire(pace=True)
+        watch.acquire(pace=True)
+        # 200,000 net allocations over 0.1 s, then a pass over them: a
+        # pass of more than ~18 us spaces the next beyond 700
+        keep = [[k] for k in range(200_000)]
+        watch._young_mark = time.perf_counter() - 0.1
+        gc.collect(0)
+        del keep
+        t0, t1, t2 = gc.get_threshold()
+        assert watch.collections[0] == 1
+        assert prior[0] < t0 <= runtime.YOUNG_PASS_MAX_N
+        # a gen-1 pass answers no more allocations than before
+        assert t1 == prior[0] * prior[1] // t0 < prior[1]
+        assert t2 == prior[2]
+        gc.set_threshold(t0, t1, 77)    # as the full-pass pacer would
+        watch.release(pace=True)
+        assert gc.get_threshold() == (t0, t1, 77)
+        watch.release(pace=True)        # the last: all three, exactly
+        assert gc.get_threshold() == prior
+        assert gc.callbacks == callbacks
+    finally:
+        gc.set_threshold(*prior)
+        if enabled:
+            gc.enable()
+
+
+def test_young_cycles_are_still_collected_while_paced():
+    watch = runtime.GcWatch()
+    prior = gc.get_threshold()
+    enabled = gc.isenabled()
+    gc.freeze()
+    gc.enable()
+    watch.acquire(pace=True)
+    try:
+        gc.collect(2)                   # counts from zero
+        # a 1-s young pass at 20,000 net allocations per second wants
+        # 380,000 between passes; the cap holds it to YOUNG_PASS_MAX_S
+        t = time.perf_counter()
+        watch._young_mark, watch._count0 = t - 1.0, 20_000
+        watch._pace_young(t, t + 1.0)
+        cap = math.ceil(20_000 * runtime.YOUNG_PASS_MAX_S)
+        assert cap < 380_000
+        assert gc.get_threshold()[0] == cap
+        cycles = [[] for _ in range(10_000)]
+        for c in cycles:
+            c.append(c)
+        del cycles, c
+        stats = gc.get_stats()
+        collected = stats[0]["collected"] + stats[1]["collected"]
+        n = watch.collections[0] + watch.collections[1]
+        keep: list = []
+        for k in range(2 * cap):
+            keep.append([k])            # survivors: the net count grows
+            if watch.collections[0] + watch.collections[1] > n:
+                break
+        # the next young pass came within the cap's allocations and
+        # freed the cycles born young
+        assert watch.collections[0] + watch.collections[1] == n + 1
+        assert len(keep) <= cap
+        stats = gc.get_stats()
+        assert (stats[0]["collected"] + stats[1]["collected"]
+                >= collected + 10_000)
     finally:
         watch.release(pace=True)
         gc.unfreeze()
@@ -410,6 +515,8 @@ def test_notary_engages_the_pacer_until_stop():
     assert watch._paced == paced + 1
     assert svc.metrics.get("Runtime.GcFullThreshold").value() == (
         gc.get_threshold()[2])
+    assert svc.metrics.get("Runtime.GcYoungThreshold").value() == (
+        gc.get_threshold()[0])
     svc.stop()
     svc.stop()                          # idempotent: one release
     assert watch._paced == paced
