@@ -260,13 +260,13 @@ def _notary_fixture(batch: int, batch_verifier=None):
 
 def _notary_rate(
     notary, alice, spends, batch: int, iters: int,
-    shards: int, workers: bool, chunk: int,
+    shards: int, chunk: int,
     verifier=None, report_phases: bool = False,
 ) -> float:
     """Measured notarisations/s for ONE commit-plane configuration:
     every spend queued (routed to its owning shard when sharded), then
-    drained by one flush — a dispatch-all-then-consume wave or N
-    worker-thread pipelines — through the real service code."""
+    drained by one flush — a dispatch-all-then-consume wave over the
+    shards — through the real service code."""
     from corda_tpu.node.notary import (
         BatchingNotaryService,
         InMemoryUniquenessProvider,
@@ -300,7 +300,6 @@ def _notary_rate(
         fresh_uniqueness(),
         max_batch=batch,               # one deep flush per pass
         shards=shards,
-        shard_workers=workers and shards > 1,
         shard_verifiers=shard_verifiers,
         shard_queue_depth=batch,       # the bench fills the whole plane
     )
@@ -355,7 +354,7 @@ def _notary_rate(
             )
         return batch * iters / dt
     finally:
-        svc.stop()                    # shard worker threads, if any
+        svc.stop()                    # drops its GC pacing hold
 
 
 def _notary_metric(batch: int, iters: int) -> dict:
@@ -375,7 +374,7 @@ def _notary_metric(batch: int, iters: int) -> dict:
     from corda_tpu.crypto.batch_verifier import TpuBatchVerifier
 
     chunk = min(int(os.environ.get("BENCH_CHUNK", "4096")), batch)
-    shards, workers, sweep = _shard_sweep_config()
+    shards, sweep = _shard_sweep_config()
     # chunk < batch => the SPI pipelines each shard's flush across
     # chunks: the host stages chunk k+1 while the device verifies k
     verifier = TpuBatchVerifier(batch_sizes=(chunk,))
@@ -387,7 +386,7 @@ def _notary_metric(batch: int, iters: int) -> dict:
         rates[str(n)] = round(
             _notary_rate(
                 notary, alice, spends, batch, iters,
-                shards=n, workers=workers, chunk=chunk,
+                shards=n, chunk=chunk,
                 verifier=verifier, report_phases=(n == shards),
             ),
             1,
@@ -407,7 +406,6 @@ def _notary_metric(batch: int, iters: int) -> dict:
         "shards": int(best),
         "shards_requested": shards,
         "per_shard_depth": -(-batch // int(best)),
-        "shard_workers": workers and int(best) > 1,
         # the 16384 clamp is lifted: the measured flush IS the
         # requested depth, so saturation only ever reads true again if
         # a future bound clamps it (kept for bench_history continuity)
@@ -421,13 +419,12 @@ def _notary_metric(batch: int, iters: int) -> dict:
     return out
 
 
-def _shard_sweep_config() -> tuple[int, bool, list[int]]:
+def _shard_sweep_config() -> tuple[int, list[int]]:
     """ONE parse of the shard-bench env knobs, shared by the notary
     and commit-plane metrics so their records cannot drift:
-    (BENCH_SHARDS, BENCH_SHARD_WORKERS, sorted sweep counts — the
-    BENCH_SHARD_SWEEP list unioned with {1, shards})."""
+    (BENCH_SHARDS, sorted sweep counts — the BENCH_SHARD_SWEEP list
+    unioned with {1, shards})."""
     shards = max(1, int(os.environ.get("BENCH_SHARDS", "4")))
-    workers = os.environ.get("BENCH_SHARD_WORKERS", "0") != "0"
     sweep_env = os.environ.get("BENCH_SHARD_SWEEP", "")
     sweep = sorted(
         {
@@ -437,7 +434,7 @@ def _shard_sweep_config() -> tuple[int, bool, list[int]]:
         }
         | {1, shards}
     )
-    return shards, workers, sweep
+    return shards, sweep
 
 
 class _AcceptAllVerifier:
@@ -463,7 +460,7 @@ def _commit_plane_metric(batch: int, iters: int) -> dict:
     on a CPU-only container, where the real-verify notary metric is
     link/device-bound and meaningless."""
     net, notary, alice, spends = _notary_fixture(batch)
-    shards, workers, sweep = _shard_sweep_config()
+    shards, sweep = _shard_sweep_config()
     # the stub replaces the hub verifier for every configuration
     notary.services._batch_verifier = _AcceptAllVerifier()
     rates: dict[str, float] = {}
@@ -471,7 +468,7 @@ def _commit_plane_metric(batch: int, iters: int) -> dict:
         rates[str(n)] = round(
             _notary_rate(
                 notary, alice, spends, batch, iters,
-                shards=n, workers=workers, chunk=batch,
+                shards=n, chunk=batch,
                 verifier=None,
             ),
             1,
@@ -485,7 +482,6 @@ def _commit_plane_metric(batch: int, iters: int) -> dict:
         "flush_depth": batch,
         "shards": shards,
         "per_shard_depth": -(-batch // shards),
-        "shard_workers": workers and shards > 1,
         "verify_stubbed": True,
         "shard_sweep": rates,
     }
@@ -755,7 +751,7 @@ def _trace_metric(batch: int, iters: int, cpu: bool = False) -> dict:
                 raise SystemExit(f"trace metric ingest failed: {e.error}")
             fut = FlowFuture()
             futs.append(fut)
-            svc._pending.append(
+            svc.enqueue_pending(
                 _PendingNotarisation(e.stx, requester, fut, span=e.span)
             )
         svc.flush()
@@ -1060,7 +1056,7 @@ def _qos_metric(batch: int, iters: int) -> dict:
                 (arrival, clock.now_micros(), deadline, f.result())
             )
         )
-        svc._pending.append(
+        svc.enqueue_pending(
             _PendingNotarisation(
                 stx, requester, fut,
                 deadline=deadline, arrival_micros=arrival,
@@ -1091,8 +1087,8 @@ def _qos_metric(batch: int, iters: int) -> dict:
         # a capped ON run can leave requeued backlog behind its drain
         # ticks; drop it so the OFF pass measures ONLY its own offered
         # load (apples-to-apples A/B)
-        svc._pending = []
-        svc._oldest_arrival = None
+        for shard in svc._shards:
+            shard.pending.clear()
         log: list = []
         it = iter(spends[base:])
         t0 = _time.perf_counter()
@@ -1199,14 +1195,14 @@ def _health_metric(batch: int, iters: int) -> dict:
         for stx in spends:
             fut = FlowFuture()
             futs.append(fut)
-            svc._pending.append(
+            svc.enqueue_pending(
                 _PendingNotarisation(stx, requester, fut)
             )
         svc.tick()                   # flush + heartbeat
         if monitor is not None:
             monitor.tick()           # watchdog + rules + canary launch
         wall = _time.perf_counter() - t0
-        if monitor is not None and svc._pending:
+        if monitor is not None and svc.backlog():
             # serve the just-launched canary OUTSIDE the timed window:
             # left pending, the NEXT (baseline) rep would flush it
             # inside ITS timing and understate the measured overhead
@@ -1342,7 +1338,7 @@ def _perf_metric(batch: int, iters: int) -> dict:
         for stx in spends:
             fut = FlowFuture()
             futs.append(fut)
-            svc._pending.append(_PendingNotarisation(stx, requester, fut))
+            svc.enqueue_pending(_PendingNotarisation(stx, requester, fut))
         svc.flush()
         wall = _time.perf_counter() - t0
         for fut in futs:
@@ -1423,7 +1419,7 @@ def _device_metric(batch: int, iters: int) -> dict:
         for stx in spends:
             fut = FlowFuture()
             futs.append(fut)
-            svc._pending.append(_PendingNotarisation(stx, requester, fut))
+            svc.enqueue_pending(_PendingNotarisation(stx, requester, fut))
         svc.flush()
         if plane is not None:
             plane.tick()
@@ -1613,7 +1609,7 @@ def _wire_metric(batch: int, iters: int) -> dict:
             for i, _ in enumerate(inbox):
                 fut = FlowFuture()
                 futs.append(fut)
-                svc._pending.append(
+                svc.enqueue_pending(
                     _PendingNotarisation(spends[i], requester, fut)
                 )
             svc.flush()
@@ -1652,7 +1648,7 @@ def _wire_metric(batch: int, iters: int) -> dict:
                 for stx in spends:
                     fut = FlowFuture()
                     futs.append(fut)
-                    svc._pending.append(
+                    svc.enqueue_pending(
                         _PendingNotarisation(stx, requester, fut)
                     )
                 svc.flush()
@@ -2971,7 +2967,7 @@ def _quick(metric: str) -> None:
       shards — the sharded commit plane (round 6) at a tiny depth with
                verification stubbed: asserts every request answers
                with a signature across 1/2/4-shard configurations
-               (inline wave AND worker threads) and that the sweep
+               (each flushed as one wave) and that the sweep
                record is well-formed — the deterministic correctness
                gate is tests/test_sharded_notary.py.
       fleet  — the simulated-time fleet soak (round 8): a small

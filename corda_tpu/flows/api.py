@@ -100,10 +100,10 @@ class FlowFuture:
     """Completable future resolved on the node's pump thread (services
     that finish later — Raft quorum, worker pools — hand these to
     flows; CordaFuture's role in the reference). Registration and
-    resolution are lock-protected: the sharded notary's worker threads
-    add done-callbacks (qos latency, span end) while the pump thread
-    resolves, and an unlocked append racing the callback swap would
-    silently drop the callback."""
+    resolution are lock-protected: a thread other than the pump may
+    add done-callbacks (a submitting client, a consensus commit) while
+    the pump thread resolves, and an unlocked append racing the
+    callback swap would silently drop the callback."""
 
     def __init__(self):
         self.done = False

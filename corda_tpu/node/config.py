@@ -82,10 +82,6 @@ class NodeConfig:
     # commitlog runs a ONE-WAY boot migration out of the sqlite
     # tables; accept/reject decisions are bit-exact across backends.
     notary_state_store: str = "sqlite"
-    # give every shard a dedicated flush worker thread (the pump then
-    # only routes and resolves answers); False flushes shards from the
-    # pump tick as a dispatch-all-then-consume wave
-    notary_shard_workers: bool = False
     # durable intake WAL (batching notary only, round 9): admitted
     # requests journal to a sqlite intent table BEFORE queueing and
     # replay through the normal flush path on boot — in-flight-at-kill
@@ -263,10 +259,6 @@ class NodeConfig:
             raise ConfigError(
                 "notary_shards requires notary = 'batching' (only the "
                 "batching notary has a sharded commit plane)"
-            )
-        if self.notary_shard_workers and self.notary_shards <= 1:
-            raise ConfigError(
-                "notary_shard_workers requires notary_shards > 1"
             )
         if self.notary_state_store not in ("sqlite", "commitlog"):
             raise ConfigError(
@@ -488,8 +480,6 @@ def write_config(cfg: NodeConfig, path: str) -> None:
         emit("notary_batch_wait_micros", cfg.notary_batch_wait_micros)
     if cfg.notary_shards:
         emit("notary_shards", cfg.notary_shards)
-        if cfg.notary_shard_workers:
-            emit("notary_shard_workers", cfg.notary_shard_workers)
     if cfg.notary_intent_wal:
         emit("notary_intent_wal", cfg.notary_intent_wal)
     if cfg.notary_state_store != "sqlite":

@@ -860,7 +860,6 @@ class Node:
                     metrics=self.metrics,
                     qos=self.qos,
                     shards=max(shards, 1),
-                    shard_workers=self.config.notary_shard_workers,
                     shard_verifiers=shard_verifiers,
                     degraded_fallback=self.config.notary_degraded_fallback,
                     intent_journal=intent_journal,
@@ -1154,7 +1153,7 @@ class Node:
         self.smm.stop()
         notary = getattr(self.services, "notary_service", None)
         if isinstance(notary, BatchingNotaryService):
-            notary.stop()   # shard worker threads, when running
+            notary.stop()   # its hold on the GC pacing watch
         if getattr(self, "xshard", None) is not None:
             self.xshard.stop()
         if self.raft is not None:

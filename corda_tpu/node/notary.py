@@ -823,65 +823,61 @@ class _PendingNotarisation:
     intent_seq: Optional[int] = None
 
 
-class _ShardAnswer:
-    """Future proxy used by threaded shard workers: `set_result` lands
-    the outcome on the notary's completion queue instead of resolving
-    the real FlowFuture from a worker thread — the pump thread drains
-    the queue and resolves, so flow resumption stays single-threaded
-    (FlowFuture's contract). Duck-types the subset of the future
-    surface the flush paths touch."""
-
-    __slots__ = ("future", "_queue", "done")
-
-    def __init__(self, future, queue):
-        self.future = future
-        self._queue = queue
-        self.done = False
-
-    def set_result(self, value) -> None:
-        if self.done:
-            return
-        self.done = True
-        self._queue.append((self.future, value))
-
-    def add_done_callback(self, cb) -> None:
-        # callbacks belong on the REAL future: they fire on the pump
-        # thread when the completion drains, which is where qos/trace
-        # observers expect to run
-        self.future.add_done_callback(cb)
-
-
 class _NotaryShard:
-    """One slice of the sharded commit plane: a bounded pending queue,
-    its own flush state, a (possibly device-pinned) verifier handle and
-    per-shard liveness/metric hooks. The BatchingNotaryService routes
-    requests here by state-ref prefix (shard_of_tx) and either flushes
-    shards inline from the pump tick or hands each one to a dedicated
-    worker thread."""
+    """One slice of the batching notary's commit plane: a bounded
+    pending queue, a (possibly device-pinned) verifier handle and its
+    liveness/metric hooks. The BatchingNotaryService owns `n_shards` of
+    them (one on the one-shard plane), routes requests here by
+    state-ref prefix (shard_of_tx) and flushes them from the pump in
+    waves. `tag` is the shard's id on a plane of several and None on a
+    plane of one: an untagged shard registers no Notary.Shard<k>.*
+    metric, and flushes, heartbeats and QoS lanes name no shard."""
 
     __slots__ = (
-        "id", "pending", "oldest_arrival", "cond", "verifier",
+        "id", "tag", "pending", "oldest_arrival", "cond", "verifier",
         "heartbeat", "queue_bound", "flushes", "requests", "answered",
-        "wake", "busy",
+        "done",
     )
 
-    def __init__(self, sid: int, verifier, queue_bound: int, metrics):
+    def __init__(
+        self, sid: int, tagged: bool, verifier, queue_bound: int, metrics
+    ):
         self.id = sid
+        self.tag = sid if tagged else None
         self.pending: list[_PendingNotarisation] = []
         self.oldest_arrival: Optional[int] = None
+        # guards pending/oldest_arrival: submit() may route from a
+        # thread other than the pump
         self.cond = locks.make_condition("_NotaryShard.cond")
         self.verifier = verifier       # None = the hub's shared verifier
-        self.heartbeat = None          # attach_health wires one per shard
+        self.heartbeat = None          # attach_health wires tagged shards
         self.queue_bound = queue_bound
-        self.flushes = metrics.counter(f"Notary.Shard{sid}.Flushes")
-        self.requests = metrics.counter(f"Notary.Shard{sid}.Requests")
-        self.answered = metrics.counter(f"Notary.Shard{sid}.Answered")
-        metrics.gauge(f"Notary.Shard{sid}.Depth", lambda: len(self.pending))
-        self.wake = False              # worker flush requested
-        self.busy = False              # a flush of this shard is running
+        # `done(answered)` ends each flush of this shard: a tagged shard
+        # counts it and beats its heartbeat, an untagged one has neither
+        self.done = _untracked_flush
+        if tagged:
+            self.flushes = metrics.counter(f"Notary.Shard{sid}.Flushes")
+            self.requests = metrics.counter(f"Notary.Shard{sid}.Requests")
+            self.answered = metrics.counter(f"Notary.Shard{sid}.Answered")
+            metrics.gauge(
+                f"Notary.Shard{sid}.Depth", lambda: len(self.pending)
+            )
+            self.done = self._count_flush
 
     def depth(self) -> int:
         return len(self.pending)
+
+    def _count_flush(self, answered: int) -> None:
+        self.flushes.inc()
+        if answered:
+            self.requests.inc(answered)
+            self.answered.inc(answered)
+        if self.heartbeat is not None:
+            self.heartbeat.beat(progress=answered)
+
+
+def _untracked_flush(answered: int) -> None:
+    """An untagged shard's flush: the Notary.* counters are its own."""
 
 
 # the pump's states between flushes and their profiler regions
@@ -891,7 +887,8 @@ _PUMP_REGIONS = {"hold": "notary.hold", "starved": "notary.starved"}
 class _FlushMarks(list):
     """One flush's phase intervals [(phase, t0, t1)] in mark order,
     and the profiler region open over the phase in progress (None off
-    a capture). A sharded flush's regions carry its `shard` id."""
+    a capture). A tagged shard's regions and spans carry its `shard`
+    id."""
 
     __slots__ = ("region", "args")
 
@@ -914,13 +911,18 @@ class BatchingNotaryService(NotaryService):
     (SURVEY §7 Phase 4).
 
     `process` enqueues the request and suspends the service flow on a
-    future; `flush` (driven by the node pump tick, or immediately when
-    `max_batch` requests are queued) drains the queue:
+    future; the pump's `tick` (or `flush`, or a shard reaching its cap)
+    drains the queues:
 
       queue -> ONE BatchSignatureVerifier dispatch over every pending
       transaction's signatures (the SPI pads/buckets into fixed XLA
       shapes) -> per-tx required-signer/contract/time-window checks ->
       uniqueness commit in arrival order -> scatter signed replies.
+
+    The queues are `shards` `_NotaryShard`s (one by default), flushed
+    by ONE driver, `_flush_wave`: it stages and dispatches every given
+    shard's batch, then consumes them in shard order. A one-shard
+    flush is a wave of one.
 
     Under the pump model the batch window is one delivery round: every
     request that arrived since the last quiescent point shares a single
@@ -943,7 +945,6 @@ class BatchingNotaryService(NotaryService):
         metrics: Optional[MetricRegistry] = None,
         qos=None,
         shards: int = 1,
-        shard_workers: bool = False,
         shard_verifiers: Optional[list] = None,
         shard_queue_depth: int = 0,
         degraded_fallback: bool = True,
@@ -973,23 +974,20 @@ class BatchingNotaryService(NotaryService):
         `shards` > 1 partitions the COMMIT PLANE (round-6 tentpole):
         requests route by state-ref prefix (shard_of_tx) onto N
         independent shards, each with its own bounded pending queue,
-        flush pipeline, uniqueness partition (pass a
-        ShardedUniquenessProvider — any provider works, but only a
-        partitioned one commits concurrently) and, when
-        `shard_verifiers` is given (crypto/batch_verifier.py
-        per_shard_verifiers: one device-pinned TpuBatchVerifier per
-        mesh device, cycled over the shards), its own per-device
-        verify dispatch so each shard's batch lands on its own chip.
-        Cross-shard
-        transactions take the provider's two-phase reserve→commit.
-        `shard_workers=True` additionally gives every shard a dedicated
-        flush thread (the pump tick then only routes + drains answers);
-        False flushes shards from the tick in a dispatch-all-then-
-        consume wave, which still overlaps device compute across
-        shards. `shard_queue_depth` bounds each shard's pending queue
-        (0 = 4x max_batch); a full queue triggers that shard's flush.
-        shards == 1 keeps the original single-queue hot path
-        bit-for-bit.
+        uniqueness partition (pass a ShardedUniquenessProvider — any
+        batch-synchronous provider works, but only a partitioned one
+        commits per shard) and, when `shard_verifiers` is given
+        (crypto/batch_verifier.py per_shard_verifiers: one
+        device-pinned TpuBatchVerifier per mesh device, cycled over the
+        shards), its own per-device verify dispatch so each shard's
+        batch lands on its own chip. Cross-shard transactions take the
+        provider's two-phase reserve→commit. The pump flushes the
+        shards in a dispatch-all-then-consume wave, which overlaps
+        device compute across shards. `shard_queue_depth` bounds each
+        shard's pending queue (0 = 4x max_batch); a full queue makes
+        that shard's flush due. shards == 1 is the same plane with one
+        untagged shard: no per-shard metric, heartbeat, span attribute
+        or QoS lane.
 
         `degraded_fallback` (round-9 fault plane): a device/kernel
         exception at the verify dispatch seam retries once on the
@@ -1017,12 +1015,9 @@ class BatchingNotaryService(NotaryService):
         )
         self.max_batch = max_batch
         self.max_wait_micros = max_wait_micros
-        self.qos = qos
-        self._pending: list[_PendingNotarisation] = []
         self._ingest_ring = None   # attach_ingest: pre-decoded arrivals
-        self._oldest_arrival: Optional[int] = None
         self._health_heartbeat = None   # attach_health: flush-loop liveness
-        self._perf = None               # attach_perf: attribution plane
+        self._perf = None               # attach_perf: per-wave observer
         self.txstory = None             # attach_txstory: lifecycle ledger
         # registry-backed metrics (scrapeable at /metrics, unlike the
         # bare ints they replace): dispatches vs requests IS the
@@ -1082,51 +1077,71 @@ class BatchingNotaryService(NotaryService):
                 "Notary.IntentUnresolved",
                 lambda: intent_journal.unresolved_count,
             )
-        # -- sharded commit plane (round 6) ----------------------------
+        # -- commit plane: n_shards queues (round 6) ---------------------
         self.n_shards = max(1, int(shards))
-        self._shards: Optional[list[_NotaryShard]] = None
-        self._completions = None       # worker mode: (future, outcome)
-        self._workers: list[threading.Thread] = []
-        self._stop_workers = False
-        self._gc_lock = locks.make_lock("BatchingNotaryService._gc_lock")
-        self._gc_depth = 0
+        tagged = self.n_shards > 1
+        if tagged and not getattr(self.uniqueness, "batch_synchronous", False):
+            raise ValueError(
+                "sharded commit plane requires a batch_synchronous "
+                "uniqueness provider (distributed providers resolve "
+                "on consensus, not on the shard flush)"
+            )
+        bound = shard_queue_depth or 4 * max_batch
+        self._shards = [
+            _NotaryShard(
+                k,
+                tagged,
+                (
+                    shard_verifiers[k % len(shard_verifiers)]
+                    if shard_verifiers else None
+                ),
+                bound,
+                self.metrics,
+            )
+            for k in range(self.n_shards)
+        ]
+        self._gc_depth = 0           # nested flushes share one GC pause
         self._gc_reenable = False
-        if self.n_shards > 1:
-            if not getattr(self.uniqueness, "batch_synchronous", False):
-                raise ValueError(
-                    "sharded commit plane requires a batch_synchronous "
-                    "uniqueness provider (distributed providers resolve "
-                    "on consensus, not on the shard flush)"
-                )
-            bound = shard_queue_depth or 4 * max_batch
-            self._shards = [
-                _NotaryShard(
-                    k,
-                    (
-                        shard_verifiers[k % len(shard_verifiers)]
-                        if shard_verifiers else None
-                    ),
-                    bound,
-                    self.metrics,
-                )
-                for k in range(self.n_shards)
-            ]
+        self._served = 0             # requests answered by every wave
+        if tagged:
             self.metrics.gauge("Notary.Shards", lambda: self.n_shards)
-            if qos is not None and hasattr(qos, "ensure_shards"):
-                qos.ensure_shards(self.n_shards)
-            if shard_workers:
-                from collections import deque
+        self.qos = qos
 
-                self._completions = deque()
-                for shard in self._shards:
-                    t = threading.Thread(
-                        target=self._shard_worker,
-                        args=(shard,),
-                        name=f"notary-shard-{shard.id}",
-                        daemon=True,
-                    )
-                    self._workers.append(t)
-                    t.start()
+    @property
+    def qos(self):
+        return self._qos
+
+    @qos.setter
+    def qos(self, qos) -> None:
+        """Attach (None: detach) a node/qos.NotaryQos and bind, for this
+        plane, the hooks the pump calls: the controller steering a
+        shard (its own lane on a plane of several, the global one on a
+        plane of one), the per-flush feedback, and on a plane of
+        several the tick's one brownout walk on the aggregate backlog
+        (an untagged shard's flush feedback walks brownout itself)."""
+        self._qos = qos
+        self._observe_backlog = None
+        if qos is None:
+            return
+        self._controller_of = getattr(
+            qos, "controller_for", lambda tag: qos.controller
+        )
+        if self.n_shards == 1:
+            self._qos_observe = lambda shard, served: qos.observe_flush(
+                served, shard.depth()
+            )
+            return
+        if hasattr(qos, "ensure_shards"):
+            qos.ensure_shards(self.n_shards)
+        lane = getattr(qos, "observe_shard_flush", None)
+
+        def observe(shard, served: int) -> None:
+            # a lane retunes only on a flush that served something
+            if served and lane is not None:
+                lane(shard.tag, served, shard.depth())
+
+        self._qos_observe = observe
+        self._observe_backlog = getattr(qos, "observe_backlog", None)
 
     # -- back-compat views over the registry-backed metrics ----------------
 
@@ -1137,22 +1152,6 @@ class BatchingNotaryService(NotaryService):
     @property
     def requests_batched(self) -> int:
         return self._requests_counter.count
-
-    @property
-    def effective_max_batch(self) -> int:
-        """The live flush-depth knob: the adaptive controller's when
-        QoS is attached, the static config otherwise."""
-        qos = self.qos
-        return qos.controller.batch if qos is not None else self.max_batch
-
-    @property
-    def effective_wait_micros(self) -> int:
-        """The live batching-window knob (see effective_max_batch)."""
-        qos = self.qos
-        return (
-            qos.controller.wait_micros if qos is not None
-            else self.max_wait_micros
-        )
 
     def process(
         self,
@@ -1168,7 +1167,7 @@ class BatchingNotaryService(NotaryService):
                 "wrong-notary",
                 f"tx names notary {stx.wtx.notary}, I am {self.identity}",
             )
-        qos = self.qos
+        qos = self._qos
         arrival = None
         if qos is not None:
             from . import qos as qoslib
@@ -1230,12 +1229,9 @@ class BatchingNotaryService(NotaryService):
             stx, requester, fut, span=span,
             deadline=deadline, arrival_micros=arrival,
         )
-        self.enqueue_pending(p)
-        if (
-            self._shards is None
-            and len(self._pending) >= self.effective_max_batch
-        ):
-            self.flush()
+        # a shard this request fills flushes now, as a wave of one
+        for shard in self.enqueue_pending(p):
+            self._flush_wave([shard])
         result = yield from wait_future(fut)
         return result
 
@@ -1248,10 +1244,9 @@ class BatchingNotaryService(NotaryService):
     ):
         """Queue one notarisation WITHOUT the flow machinery and return
         its FlowFuture (bench rigs, tests, embedded drivers). Routes to
-        the owning shard on the sharded plane; on the classic plane it
-        appends to the single pending queue. The future resolves on
-        flush (worker-mode callers drive tick()/flush() to drain
-        completions)."""
+        the owning shard and never flushes, so it may run on a thread
+        other than the pump; the future resolves on the pump's next
+        flush (tick() or flush())."""
         from ..flows.api import FlowFuture
 
         fut = FlowFuture()
@@ -1262,18 +1257,13 @@ class BatchingNotaryService(NotaryService):
         self.enqueue_pending(p)
         return fut
 
-    def enqueue_pending(self, p: _PendingNotarisation) -> None:
-        """THE queue-routing step every intake path shares (process,
-        submit, the canary probe): the owning shard on the sharded
-        plane, the single pending queue — with its oldest-arrival
-        stamp — otherwise. The canary (utils/health.notary_canary_fn)
-        MUST come through here: a bare `_pending.append` starves
-        forever on a sharded notary, whose tick only drains the shard
-        queues (the deadman would fire on a perfectly healthy node).
-        Full-batch flush triggers stay with the callers: process()
-        flushes the unsharded queue at effective_max_batch, the shard
-        router flushes a full shard itself, submit() never flushes
-        (bench rigs fill the whole plane first)."""
+    def enqueue_pending(self, p: _PendingNotarisation) -> list:
+        """THE intake step every request path shares (process, submit,
+        the canary probe, intent replay): intent journal, lifecycle
+        admit, then `_route` into the owning shard's queue. Returns the
+        shards the request filled to their batch or queue bound; only
+        process() (on the pump) flushes them at once, the other callers
+        leave them to the next tick, which finds them due."""
         journal = self.intent_journal
         fresh = p.intent_seq is None
         if journal is not None and fresh:
@@ -1291,12 +1281,7 @@ class BatchingNotaryService(NotaryService):
         # for a re-enqueued intent — either way the future's answer
         # records this transaction's one terminal event
         self._story_intake(p, fresh)
-        if self._shards is not None:
-            self._enqueue_sharded(p)
-            return
-        if not self._pending:
-            self._oldest_arrival = self.services.clock.now_micros()
-        self._pending.append(p)
+        return self._route([p])
 
     def attach_intent_journal(self, journal) -> None:
         """Wire (or detach, with None) the durable intake WAL after
@@ -1346,45 +1331,40 @@ class BatchingNotaryService(NotaryService):
         return shard_of_tx(stx, self.n_shards)
 
     def _shard_cap(self, shard) -> int:
-        qos = self.qos
-        if qos is None:
+        """A shard's batch: the depth at which its queue is full and
+        flushes — its QoS controller's batch, which also caps one
+        flush, else max_batch."""
+        if self._qos is None:
             return self.max_batch
-        if hasattr(qos, "controller_for"):
-            return qos.controller_for(shard.id).batch
-        return qos.controller.batch
+        return self._controller_of(shard.tag).batch
 
     def _shard_wait(self, shard) -> int:
-        qos = self.qos
-        if qos is None:
+        if self._qos is None:
             return self.max_wait_micros
-        if hasattr(qos, "controller_for"):
-            return qos.controller_for(shard.id).wait_micros
-        return qos.controller.wait_micros
+        return self._controller_of(shard.tag).wait_micros
 
-    def _enqueue_sharded(self, p: _PendingNotarisation):
-        shard = self._shards[shard_of_tx(p.stx, self.n_shards)]
-        if self._completions is not None:
-            # worker mode: the flush runs on the shard's thread, but
-            # FlowFutures must resolve on the pump thread — proxy the
-            # outcome through the completion queue
-            p.future = _ShardAnswer(p.future, self._completions)
-        flush_now = False
-        with shard.cond:
-            if not shard.pending:
-                shard.oldest_arrival = self.services.clock.now_micros()
-            shard.pending.append(p)
-            depth = len(shard.pending)
-            if depth >= self._shard_cap(shard) or depth >= shard.queue_bound:
-                # full batch (or full bounded queue): flush THIS shard —
-                # the others keep accumulating their own batches
-                if self._workers:
-                    shard.wake = True
-                    shard.cond.notify_all()
-                else:
-                    flush_now = True
-        if flush_now:
-            self._flush_one_shard(shard)
-        return shard
+    def _route(self, batch) -> list:
+        """Queue a batch of arrivals: grouped by owning shard, each
+        group extended onto its shard's queue under one lock hold
+        (the oldest-arrival stamp set when the queue was empty).
+        Returns the shards now at their batch or queue bound."""
+        n = self.n_shards
+        groups: list[list] = [[] for _ in range(n)]
+        for p in batch:
+            groups[shard_of_tx(p.stx, n)].append(p)
+        now = self.services.clock.now_micros()
+        full = []
+        for shard, group in zip(self._shards, groups):
+            if not group:
+                continue
+            with shard.cond:
+                if not shard.pending:
+                    shard.oldest_arrival = now
+                shard.pending.extend(group)
+                depth = len(shard.pending)
+            if depth >= min(self._shard_cap(shard), shard.queue_bound):
+                full.append(shard)
+        return full
 
     def attach_ingest(self, ring) -> None:
         """Wire the pipelined wire-ingest seam (node/ingest.py): the
@@ -1410,31 +1390,30 @@ class BatchingNotaryService(NotaryService):
         queue depth (pending + ingest ring) for livelock detection —
         a flush loop that ticks forever while its queue sits full and
         nothing resolves is wedged in a way the stall detector can't
-        see. On the sharded plane EVERY shard additionally registers
-        its own `notary.shard<k>.flush` heartbeat (beaten by its flush
-        — worker thread or inline wave — with its own queue depth), so
-        one wedged shard flips /healthz even while its siblings keep
+        see. On a plane of several shards EVERY shard additionally
+        registers its own `notary.shard<k>.flush` heartbeat (beaten by
+        the tick and by its flushes, with its own queue depth), so one
+        wedged shard flips /healthz even while its siblings keep
         serving. Pass None to detach (bench A/B rigs)."""
         if monitor is None:
             self._health_heartbeat = None
-            if self._shards is not None:
-                for shard in self._shards:
-                    shard.heartbeat = None
+            for shard in self._shards:
+                shard.heartbeat = None
             return
         self._health_heartbeat = monitor.heartbeat(
             "notary.flush",
-            queue_depth=lambda: sum(self.shard_depths())
+            queue_depth=lambda: self.backlog()
             + (
                 len(self._ingest_ring)
                 if self._ingest_ring is not None
                 else 0
             ),
         )
-        if self._shards is not None:
-            for shard in self._shards:
+        for shard in self._shards:
+            if shard.tag is not None:
                 shard.heartbeat = monitor.heartbeat(
-                    f"notary.shard{shard.id}.flush",
-                    queue_depth=(lambda s=shard: s.depth()),
+                    f"notary.shard{shard.tag}.flush",
+                    queue_depth=shard.depth,
                 )
         # degraded-mode alert (round 9): fires while the device verify
         # path is distrusted (a flush fell back to the CPU reference),
@@ -1497,35 +1476,36 @@ class BatchingNotaryService(NotaryService):
     def attach_perf(self, plane) -> None:
         """Wire the performance-attribution plane (utils/perf.py):
         every flush feeds its phase marks in — per-shard flush wall +
-        request counts for the skew window, link-blocked time for the
-        wave overlap-efficiency gauge — and the notary's served-request
+        request counts for the skew window, and on a plane of several
+        shards each wave's link-blocked time for the overlap-efficiency
+        gauge — and the notary's served-request
         counter becomes the plane's in-process
         `batching_notary_notarisations_per_sec` history key (the same
         key bench.py records, so the node can diff itself against the
         committed BENCH baseline between offline rounds). Pass None to
         detach (bench A/B rigs)."""
-        self._perf = plane
         if plane is None:
+            self._perf = None
             return
-        if self._shards is not None:
-            plane.attach_shards(
-                self.n_shards,
-                [(lambda s=shard: s.depth()) for shard in self._shards],
-            )
-        else:
-            plane.attach_shards(1, [lambda: len(self._pending)])
+        plane.attach_shards(
+            self.n_shards, [shard.depth for shard in self._shards]
+        )
+        # [(shard id, n, marks)] per wave: one flush observation on a
+        # one-shard plane, a wave observation on a plane of several
+        self._perf = (
+            plane.observe_wave if self.n_shards > 1
+            else lambda feeds: plane.observe_flush(*feeds[0])
+        )
         plane.watch_rate(
             "batching_notary_notarisations_per_sec",
             lambda: self._requests_counter.count,
         )
 
     def backlog(self) -> int:
-        """Live pending depth across the commit plane (all shards, or
-        the single queue) — the device plane's starvation signal and
-        the fleet rigs' public depth read."""
-        if self._shards is not None:
-            return sum(shard.depth() for shard in self._shards)
-        return len(self._pending)
+        """Live pending depth across the commit plane — the device
+        plane's starvation signal and the fleet rigs' public depth
+        read."""
+        return sum(shard.depth() for shard in self._shards)
 
     def attach_device(self, plane) -> None:
         """Wire the device-telemetry plane (utils/device_telemetry):
@@ -1537,52 +1517,41 @@ class BatchingNotaryService(NotaryService):
         (re-attach a different notary to repoint a plane)."""
         if plane is None:
             return
-        if self._shards is not None:
-            plane.attach_queues(
-                [(lambda s=shard: s.depth()) for shard in self._shards],
-                [
-                    getattr(
-                        getattr(shard.verifier, "device", None),
-                        "id", None,
-                    )
-                    for shard in self._shards
-                ],
-            )
-        else:
-            plane.attach_queues([lambda: len(self._pending)], [None])
+        plane.attach_queues(
+            [shard.depth for shard in self._shards],
+            [
+                getattr(getattr(shard.verifier, "device", None), "id", None)
+                for shard in self._shards
+            ],
+        )
         plane.watch_fallback(
             lambda: self.degraded, lambda: self.degraded_evidence
         )
 
     def _drain_ingest(self) -> None:
+        """Route every batch the ingest ring holds (pump thread). A
+        shard the drain fills is due: the tick that drained it flushes
+        it, with every other shard with work, through `flush()`."""
         ring = self._ingest_ring
         if ring is None:
             return
         story = self.txstory
-        if self._shards is not None:
-            for batch in ring.drain():
-                for p in batch:
-                    if story is not None:
-                        # ring arrivals bypass enqueue_pending (no
-                        # intent journal on the wire path) but still
-                        # admit into the lifecycle ledger
-                        self._story_intake(p, fresh=True)
-                    self._enqueue_sharded(p)
-            return
         for batch in ring.drain():
             if story is not None:
                 for p in batch:
+                    # ring arrivals bypass enqueue_pending (no intent
+                    # journal on the wire path) but still admit into
+                    # the lifecycle ledger
                     self._story_intake(p, fresh=True)
-            self._pending.extend(batch)
-        if self._pending and self._oldest_arrival is None:
-            self._oldest_arrival = self.services.clock.now_micros()
+            self._route(batch)
 
     def tick(self) -> int:
         """Pump hook (MockNetwork `node.ticks` / Node._tick_services):
-        flush whatever accumulated during the last delivery round —
-        unless a batching deadline is set and neither it nor max_batch
-        has been reached yet. Returns requests answered (0 = held or
-        quiescent).
+        route fresh ingest arrivals, then, once any shard's batch is
+        due — its batching deadline passed, or its batch filled —
+        `flush()` every shard with work as one wave, so no shard falls
+        due inside the wave and takes the next one alone. Returns
+        requests answered (0 = held or quiescent).
 
         Each tick ends held, starved or flushed (`_pump_episode`)."""
         now = time.perf_counter()
@@ -1591,34 +1560,45 @@ class BatchingNotaryService(NotaryService):
             # (the fsync discipline of the fabric journals): answers
             # buffered since the last tick clear in ONE transaction
             self.intent_journal.flush_resolved()
-        if self._shards is not None:
-            return self._tick_sharded(now)
         self._drain_ingest()
+        clock_now = self.services.clock.now_micros()
+        due = False
+        backlog = 0
+        for shard in self._shards:
+            with shard.cond:
+                n = len(shard.pending)
+                oldest = shard.oldest_arrival or 0
+            backlog += n
+            if n:
+                wait = self._shard_wait(shard)
+                due = due or not (
+                    wait and n < self._shard_cap(shard)
+                    and clock_now - oldest < wait
+                )
+            if shard.heartbeat is not None:
+                # quiescent or held, not wedged: the loop is alive, it
+                # just chose to wait — zero progress, which is exactly
+                # what livelock detection should see while a batch forms
+                shard.heartbeat.beat()
+        self._pump_episode(
+            None if due else "hold" if backlog else "starved", now
+        )
+        answered = 0
+        if due:
+            served = self._served
+            # through the attribute, so a wrapper installed on it runs
+            self.flush()
+            answered = self._served - served
+        if self._observe_backlog is not None:
+            # ONE brownout observation per pump round, on the aggregate
+            # backlog — a lane's flush feedback only retunes that
+            # shard's controller (a hot shard cannot brown out the node
+            # by itself; a node-wide backlog still does)
+            self._observe_backlog(backlog)
         hb = self._health_heartbeat
-        n = len(self._pending)
-        if not n:
-            self._pump_episode("starved", now)
-            if hb is not None:
-                hb.beat()
-            return 0
-        if self.effective_wait_micros and n < self.effective_max_batch:
-            age = (
-                self.services.clock.now_micros()
-                - (self._oldest_arrival or 0)
-            )
-            if age < self.effective_wait_micros:
-                # held, not wedged: the loop is alive (beat), it just
-                # chose to wait — zero progress, which is exactly what
-                # livelock detection should see while a batch forms
-                self._pump_episode("hold", now)
-                if hb is not None:
-                    hb.beat()
-                return 0
-        self._pump_episode(None, now)
-        self.flush()
         if hb is not None:
-            hb.beat(progress=n)
-        return n
+            hb.beat(progress=answered)
+        return answered
 
     def _pump_episode(self, state: Optional[str], now: float) -> None:
         """The pump's state at a tick: "hold" (work pending below the
@@ -1643,98 +1623,11 @@ class BatchingNotaryService(NotaryService):
         if state is not None:
             self._pump_region = tracing.open_region(_PUMP_REGIONS[state])
 
-    def _tick_sharded(self, t_tick: float) -> int:
-        """One pump round over the sharded commit plane: route fresh
-        ingest arrivals, then flush every shard whose batch is due —
-        inline as a dispatch-all-then-consume wave (device compute for
-        shard k overlaps host work for shard j) that also takes every
-        other shard with work, or by waking each due shard's worker
-        thread. Completions from worker flushes resolve
-        HERE, on the pump thread."""
-        self._drain_ingest()
-        now = self.services.clock.now_micros()
-        due: list[_NotaryShard] = []
-        held: list[_NotaryShard] = []
-        woken = False
-        total_backlog = 0
-        for shard in self._shards:
-            with shard.cond:
-                n = len(shard.pending)
-                total_backlog += n
-                if not n:
-                    if not self._workers and shard.heartbeat is not None:
-                        shard.heartbeat.beat()   # alive, quiescent
-                    continue
-                wait = self._shard_wait(shard)
-                if wait and n < self._shard_cap(shard):
-                    age = now - (shard.oldest_arrival or 0)
-                    if age < wait:
-                        # held, not wedged (see the unsharded tick)
-                        if shard.heartbeat is not None:
-                            shard.heartbeat.beat()
-                        held.append(shard)
-                        continue
-                if self._workers:
-                    shard.wake = True
-                    shard.cond.notify_all()
-                    woken = True
-                else:
-                    due.append(shard)
-        if due and held:
-            # a wave carries every shard with work: a shard held back
-            # would fall due inside the wave and take the next one
-            # alone, and the shards' batches would drift apart into
-            # back-to-back waves of a few shards each
-            due = sorted(due + held, key=lambda s: s.id)
-        self._pump_episode(
-            None if due or woken
-            else "hold" if total_backlog else "starved",
-            t_tick,
-        )
-        answered = self._flush_wave(due) if due else 0
-        answered += self._drain_completions()
-        if self.qos is not None and hasattr(self.qos, "observe_backlog"):
-            # ONE brownout observation per pump round, on the aggregate
-            # backlog — per-shard flush feedback only retunes that
-            # shard's controller (a hot shard cannot brown out the node
-            # by itself; a node-wide backlog still does)
-            self.qos.observe_backlog(total_backlog)
-        hb = self._health_heartbeat
-        if hb is not None:
-            hb.beat(progress=answered)
-        return answered
-
-    def _drain_completions(self) -> int:
-        """Resolve worker-flushed answers on the calling (pump) thread."""
-        q = self._completions
-        if not q:
-            return 0
-        n = 0
-        while True:
-            try:
-                fut, outcome = q.popleft()
-            except IndexError:
-                break
-            fut.set_result(outcome)
-            n += 1
-        return n
-
     def stop(self) -> None:
-        """Stop shard worker threads and drop this service's pacing
-        hold on the process GC watch."""
+        """Drop this service's pacing hold on the process GC watch."""
         if self._gc_watched:
             self._gc_watched = False
             self._gc_watch.release(pace=True)
-        if not self._workers:
-            return
-        self._stop_workers = True
-        for shard in self._shards or ():
-            with shard.cond:
-                shard.cond.notify_all()
-        for t in self._workers:
-            t.join(timeout=5)
-        self._workers = []
-        self._drain_completions()
 
     def _mark(
         self, phase: str, t_prev: float, marks: Optional[_FlushMarks] = None,
@@ -1766,85 +1659,51 @@ class BatchingNotaryService(NotaryService):
         # sweeps were 68% of the serving wall (BASELINE.md round-3
         # profile). Suspend automatic GC for the bounded flush body;
         # collection resumes (and catches up) between pump ticks.
-        # Refcounted: concurrent shard-worker flushes share one pause.
-        with self._gc_lock:
-            self._gc_depth += 1
-            if self._gc_depth == 1:
-                self._gc_reenable = gc.isenabled()
-                if self._gc_reenable:
-                    gc.disable()
+        # Every flush runs on the pump thread; the depth counts a wave
+        # a flush's callbacks start inside another.
+        self._gc_depth += 1
+        if self._gc_depth == 1:
+            self._gc_reenable = gc.isenabled()
+            if self._gc_reenable:
+                gc.disable()
 
     def _gc_resume(self) -> None:
-        with self._gc_lock:
-            self._gc_depth -= 1
-            if self._gc_depth == 0 and self._gc_reenable:
-                gc.enable()
+        self._gc_depth -= 1
+        if self._gc_depth == 0 and self._gc_reenable:
+            gc.enable()
 
-    def flush(self) -> None:
-        """Drain everything pending NOW. On the sharded plane this
-        flushes every shard: inline as one dispatch-all-then-consume
-        wave, or — with worker threads — by waking every shard and
-        blocking until they go idle, then resolving the completions on
-        the calling thread (which acts as the pump)."""
-        if self._pump_state is not None:
-            self._pump_episode(None, time.perf_counter())
+    def flush(self) -> int:
+        """Flush what is pending NOW as one wave (pump thread): route
+        pre-ingested arrivals, then flush every shard with work. Under
+        QoS a shard's flush is capped at its controller's batch and
+        the overflow waits for the next flush. Returns requests
+        answered."""
         if self.intent_journal is not None:
             self.intent_journal.flush_resolved()
         self._drain_ingest()   # pre-ingested arrivals join this flush
-        if self._shards is not None:
-            if self._workers:
-                for shard in self._shards:
-                    with shard.cond:
-                        if shard.pending:
-                            shard.wake = True
-                            shard.cond.notify_all()
-                for shard in self._shards:
-                    with shard.cond:
-                        # bounded waits: a stopped plane (or a worker
-                        # killed by a BaseException) must not park this
-                        # caller forever on a predicate no thread will
-                        # ever satisfy
-                        while not shard.cond.wait_for(
-                            lambda: not shard.pending and not shard.busy,
-                            timeout=0.5,
-                        ):
-                            if self._stop_workers or not any(
-                                t.is_alive() for t in self._workers
-                            ):
-                                break
-                self._drain_completions()
-            else:
-                self._flush_wave(
-                    [s for s in self._shards if s.pending]
-                )
-            return
-        self._gc_pause()
-        try:
-            self._flush_inner()
-        finally:
-            self._gc_resume()
-
-    # -- sharded flush machinery (round 6) ----------------------------------
+        return self._flush_wave([s for s in self._shards if s.pending])
 
     def _take_pending(self, shard) -> list[_PendingNotarisation]:
         with shard.cond:
             pending, shard.pending = shard.pending, []
             shard.oldest_arrival = None
-            if pending:
-                shard.busy = True
             return pending
 
     def _flush_wave(self, shards: list) -> int:
-        """Inline sharded flush: phase A stages + dispatches EVERY due
+        """THE flush driver: phase A stages + dispatches EVERY given
         shard's verify batch (per-device, async), phase B consumes them
         in shard order — so while shard k's host validate/commit runs,
         shards k+1..N's device compute is already in flight. One GC
-        pause spans the wave.
+        pause spans the wave. A one-shard flush is a wave of one.
 
         Under a capture the wave is the region `notary.wave`, with the
         shards it flushed, the plane's shard count (`n_shards`), the
         transactions it carried (`frames`) and the deepest shard's
         count (`max_frames`)."""
+        if self._pump_state is not None:
+            # a flush outside the tick (process, flush()) ends the
+            # pump's hold or starved episode
+            self._pump_episode(None, time.perf_counter())
         if not shards:
             return 0
         depths: list[int] = []
@@ -1856,30 +1715,36 @@ class BatchingNotaryService(NotaryService):
                 pending = self._take_pending(shard)
                 if not pending:
                     continue
-                if self.qos is not None:
+                if self._qos is not None:
                     pending = self._qos_admit(pending, shard)
-                    if not pending:
-                        self._shard_done(shard, 0)
+                    if not pending:   # shed every request
+                        self._qos_feedback(pending, shard)
+                        shard.done(0)
                         continue
-                marks = _FlushMarks(shard.id)
+                # `marks` collects this flush's phase intervals; the
+                # finally below attributes them to every member frame's
+                # trace and ENDS the per-frame root spans — on every
+                # exit path (normal, streamed, dispatch failure), so
+                # upstream traces always complete
+                marks = _FlushMarks(shard.tag)
                 ctx = self._stage_and_dispatch(pending, marks, shard)
                 staged.append((shard, pending, marks, ctx))
             for shard, pending, marks, ctx in staged:
                 try:
                     if ctx is not None:
-                        self._consume_flush(ctx, marks, shard)
+                        self._consume_flush(ctx, marks)
                 finally:
-                    self._emit_flush_trace(pending, marks, shard)
-                    if self.qos is not None:
+                    self._emit_flush_trace(pending, marks)
+                    if self._qos is not None:
                         self._qos_feedback(pending, shard)
-                    self._shard_done(shard, len(pending))
+                    shard.done(len(pending))
                 depths.append(len(pending))
             if self._perf is not None and staged:
-                # one wave observation: per-shard skew feeds plus the
+                # per-shard skew feeds, plus on a plane of several the
                 # dispatch-vs-consume overlap efficiency (the wave's
                 # reason to exist — device compute of shard k+1 under
                 # host consume of shard k)
-                self._perf.observe_wave(
+                self._perf(
                     [
                         (shard.id, len(pending), marks)
                         for shard, pending, marks, _ctx in staged
@@ -1892,132 +1757,26 @@ class BatchingNotaryService(NotaryService):
                     region, shards=len(depths), n_shards=self.n_shards,
                     frames=sum(depths), max_frames=max(depths, default=0),
                 )
+        self._served += sum(depths)
         return sum(depths)
-
-    def _flush_one_shard(self, shard) -> int:
-        """Full flush pipeline for ONE shard (worker threads; also the
-        queue-full inline trigger)."""
-        pending = self._take_pending(shard)
-        if not pending:
-            return 0
-        self._gc_pause()
-        try:
-            if self.qos is not None:
-                pending = self._qos_admit(pending, shard)
-                if not pending:
-                    self._shard_done(shard, 0)
-                    return 0
-            marks = _FlushMarks(shard.id)
-            try:
-                ctx = self._stage_and_dispatch(pending, marks, shard)
-                if ctx is not None:
-                    self._consume_flush(ctx, marks, shard)
-            finally:
-                self._emit_flush_trace(pending, marks, shard)
-                if self._perf is not None:
-                    self._perf.observe_flush(shard.id, len(pending), marks)
-                if self.qos is not None:
-                    self._qos_feedback(pending, shard)
-                self._shard_done(shard, len(pending))
-            return len(pending)
-        finally:
-            self._gc_resume()
-
-    def _shard_done(self, shard, answered: int) -> None:
-        shard.flushes.inc()
-        if answered:
-            shard.requests.inc(answered)
-            shard.answered.inc(answered)
-        if shard.heartbeat is not None:
-            shard.heartbeat.beat(progress=answered)
-        with shard.cond:
-            shard.busy = False
-            shard.cond.notify_all()
-
-    def _shard_worker(self, shard) -> None:
-        """One shard's dedicated flush loop: wait for work (or a wake
-        from the router/tick), honour the batching deadline, flush.
-        Never dies — every flush path answers its futures on error, and
-        an unexpected exception here logs rather than silently wedging
-        the shard (the per-shard heartbeat would flag it anyway)."""
-        clock = self.services.clock
-        while not self._stop_workers:
-            with shard.cond:
-                shard.cond.wait_for(
-                    lambda: shard.wake or shard.pending or self._stop_workers,
-                    timeout=0.05,
-                )
-                if self._stop_workers:
-                    return
-                woken, shard.wake = shard.wake, False
-                n = len(shard.pending)
-                if not n:
-                    if shard.heartbeat is not None:
-                        shard.heartbeat.beat()   # alive, quiescent
-                    continue
-                if not woken:
-                    wait = self._shard_wait(shard)
-                    if wait and n < self._shard_cap(shard):
-                        age = clock.now_micros() - (shard.oldest_arrival or 0)
-                        if age < wait:
-                            if shard.heartbeat is not None:
-                                shard.heartbeat.beat()   # held, not wedged
-                            continue
-            try:
-                self._flush_one_shard(shard)
-            except Exception:   # noqa: BLE001 - keep the shard serving
-                import logging
-
-                logging.getLogger("corda_tpu.notary").exception(
-                    "shard %d flush failed", shard.id
-                )
-                with shard.cond:
-                    shard.busy = False
-                    shard.cond.notify_all()
 
     def shard_depths(self) -> list[int]:
         """Live pending depth per shard (health/qos introspection)."""
-        if self._shards is None:
-            return [len(self._pending)]
         return [s.depth() for s in self._shards]
 
-    def _flush_inner(self) -> None:
-        pending, self._pending = self._pending, []
-        self._oldest_arrival = None
-        if not pending:
-            return
-        if self.qos is not None:
-            pending = self._qos_admit(pending)
-            if not pending:
-                self.qos.observe_flush(0, len(self._pending))
-                return
-        # `marks` collects this flush's phase intervals; the finally
-        # attributes them to every member frame's trace and ENDS the
-        # per-frame root spans — on every exit path (normal, streamed,
-        # dispatch failure), so upstream traces always complete
-        marks = _FlushMarks()
-        try:
-            self._flush_body(pending, marks)
-        finally:
-            self._emit_flush_trace(pending, marks)
-            if self._perf is not None:
-                self._perf.observe_flush(0, len(pending), marks)
-            if self.qos is not None:
-                self._qos_feedback(pending)
-
     def _qos_admit(
-        self, pending: list[_PendingNotarisation], shard=None
+        self, pending: list[_PendingNotarisation], shard
     ) -> list[_PendingNotarisation]:
-        """Pre-stage QoS pass over one flush's intake: shed requests
-        whose deadline passed while they queued (a typed `shed` answer
-        — the client gave up; verifying it would burn a TPU batch lane
-        on a dead request), then cap the served depth at the adaptive
-        controller's batch (the owning SHARD's controller on the
-        sharded plane) so one flush cannot blow the latency budget;
-        the overflow re-queues AHEAD of newer arrivals (FIFO holds)."""
+        """Pre-stage QoS pass over one shard flush's intake: shed
+        requests whose deadline passed while they queued (a typed
+        `shed` answer — the client gave up; verifying it would burn a
+        TPU batch lane on a dead request), then cap the served depth at
+        the shard's controller batch (`_shard_cap`) so one flush cannot
+        blow the latency budget; the overflow re-queues AHEAD of newer
+        arrivals (FIFO holds)."""
         from . import qos as qoslib
 
-        qos = self.qos
+        qos = self._qos
         now = self.services.clock.now_micros()
         live: list[_PendingNotarisation] = []
         for p in pending:
@@ -2042,43 +1801,36 @@ class BatchingNotaryService(NotaryService):
                 )
             else:
                 live.append(p)
-        cap = (
-            self._shard_cap(shard) if shard is not None
-            else qos.controller.batch
-        )
+        cap = self._shard_cap(shard)
         if len(live) > cap:
             overflow = live[cap:]
             live = live[:cap]
-            arrival = (
-                overflow[0].arrival_micros
-                if overflow[0].arrival_micros is not None
-                else now
-            )
-            if shard is not None:
-                with shard.cond:
-                    shard.pending = overflow + shard.pending
-                    shard.oldest_arrival = arrival
-            else:
-                self._pending = overflow + self._pending
-                self._oldest_arrival = arrival
+            arrival = overflow[0].arrival_micros
+            if arrival is None:
+                arrival = now
+            with shard.cond:
+                shard.pending = overflow + shard.pending
+                shard.oldest_arrival = arrival
         return live
 
     def _qos_feedback(
-        self, served: list[_PendingNotarisation], shard=None
+        self, served: list[_PendingNotarisation], shard
     ) -> None:
         """Post-flush QoS pass: admitted-request completion latency
         (node-clock micros, arrival -> answer) into the histogram the
         adaptive controller reads, then one controller observation with
-        the depth served and the backlog left — the owning shard's
-        controller on the sharded plane, so a hot shard retunes ITSELF
-        without collapsing the other shards' batching windows.
+        the depth served and the backlog left (`_qos_observe`, bound
+        per plane where the QoS attaches): an untagged shard feeds the
+        global controller and walks brownout once per flush; a tagged
+        one feeds its own lane, so a hot shard retunes ITSELF without
+        collapsing the other shards' batching windows.
         Futures still open here (distributed-commit consensus resolves
         them later) record at RESOLUTION via a done callback — slow
         consensus commits must reach the p99 the controller steers by,
         or it would stretch the window while the real SLO breaches."""
-        qos = self.qos
+        qos = self._qos
         now = self.services.clock.now_micros()
-        sid = shard.id if shard is not None else None
+        sid = shard.tag
         for p in served:
             if p.arrival_micros is None:
                 continue
@@ -2091,24 +1843,20 @@ class BatchingNotaryService(NotaryService):
                         q.record_admitted(q.now_micros() - arr, shard=s)
                     )
                 )
-        if shard is not None and hasattr(qos, "observe_shard_flush"):
-            qos.observe_shard_flush(sid, len(served), shard.depth())
-        else:
-            qos.observe_flush(len(served), len(self._pending))
+        self._qos_observe(shard, len(served))
 
-    def _emit_flush_trace(self, pending, marks, shard=None) -> None:
+    def _emit_flush_trace(self, pending, marks) -> None:
         """Per-frame trace assembly: the flush phases ran batched, so
         each interval is shared across the batch and stamped into every
-        traced member's tree (batch size as an attribute; the owning
-        shard id too on the sharded plane, so per-shard alert evidence
-        — the perf plane's skew rule — can cite the traces that
-        touched the hot shard). Spans are emitted on the tracer that
-        OWNS the frame's root span, so mixed tracer setups still
-        assemble whole traces. Every flush path ends here, so the
-        profiler region a failed flush left open closes here too."""
+        traced member's tree (batch size as an attribute; a tagged
+        shard's id too, so per-shard alert evidence — the perf plane's
+        skew rule — can cite the traces that touched the hot shard).
+        Spans are emitted on the tracer that OWNS the frame's root
+        span, so mixed tracer setups still assemble whole traces. Every
+        flush ends here, so the profiler region a failed flush left
+        open closes here too."""
         marks.next_region(None)
-        n = len(pending)
-        sid = shard.id if shard is not None else None
+        attrs = {"batch": len(pending), **marks.args}
         for p in pending:
             span = p.span
             if not span or span.ended:
@@ -2119,18 +1867,10 @@ class BatchingNotaryService(NotaryService):
                 continue
             tracer = getattr(span, "_tracer", None)
             if tracer is not None:
-                if sid is not None:
-                    span.set_attribute("shard", sid)
+                for key, value in marks.args.items():
+                    span.set_attribute(key, value)
                 for phase, t0, t1 in marks:
-                    if sid is not None:
-                        tracer.span_at(
-                            "notary." + phase, span, t0, t1,
-                            batch=n, shard=sid,
-                        )
-                    else:
-                        tracer.span_at(
-                            "notary." + phase, span, t0, t1, batch=n
-                        )
+                    tracer.span_at("notary." + phase, span, t0, t1, **attrs)
             # the root ends when the request is ANSWERED: on the
             # synchronous paths every future resolved inside the flush
             # body, but a distributed provider's commit_async resolves
@@ -2145,12 +1885,7 @@ class BatchingNotaryService(NotaryService):
             else:
                 fut.add_done_callback(lambda f, s=span: s.end())
 
-    def _flush_body(self, pending, marks) -> None:
-        ctx = self._stage_and_dispatch(pending, marks)
-        if ctx is not None:
-            self._consume_flush(ctx, marks)
-
-    def _stage_and_dispatch(self, pending, marks, shard=None):
+    def _stage_and_dispatch(self, pending, marks, shard):
         """Phase A of a flush: stage every pending transaction's
         signature requests and launch the (async) SPI dispatch — on the
         shard's device-pinned verifier when one is wired, the hub's
@@ -2186,13 +1921,11 @@ class BatchingNotaryService(NotaryService):
             # flush membership: batch id + owning shard on every
             # member transaction's story, one lock hold for the batch
             self.txstory.flush_membership(
-                [str(p.stx.id) for p in pending],
-                shard=shard.id if shard is not None else None,
+                [str(p.stx.id) for p in pending], shard=shard.tag,
             )
         t = self._mark("stage", t, marks, then="dispatch")
         verifier = (
-            shard.verifier
-            if shard is not None and shard.verifier is not None
+            shard.verifier if shard.verifier is not None
             else self.services.batch_verifier
         )
         poison: set = set()
@@ -2390,7 +2123,7 @@ class BatchingNotaryService(NotaryService):
             )
         )
 
-    def _consume_flush(self, ctx, marks, shard=None) -> None:
+    def _consume_flush(self, ctx, marks) -> None:
         """Phase B of a flush: host-side resolve+contract pass, then
         consume the verify results (streamed or joined), validate,
         commit against the (possibly partitioned) uniqueness provider,

@@ -83,6 +83,15 @@ def _metric_count(registry, name: str) -> int:
     m = registry.get(name)
     return m.count if m is not None else 0
 
+
+def _drop_queued(svc) -> None:
+    """Process death of a batching notary: its queued-but-unflushed
+    requests die with the heap."""
+    for shard in svc._shards:
+        with shard.cond:
+            shard.pending.clear()
+
+
 # outcome vocabulary — one set for records, reports and assertions
 OUT_SIGNED = "signed"
 OUT_CONFLICT = "conflict"
@@ -1493,7 +1502,7 @@ class FleetSim:
             # member stops ticking — the durable NodeDatabase is the
             # only thing that survives, like a real sqlite file
             svc = node.services.notary_service
-            svc._pending.clear()
+            _drop_queued(svc)
             journal = self._member_intents.get(node.name)
             if journal is not None:
                 journal.lose_unflushed_resolutions()
@@ -1600,13 +1609,7 @@ class FleetSim:
         if self.flavour != "batching":
             raise ValueError("kill_notary is the batching-flavour crash")
         node = self.members[0]
-        svc = node.services.notary_service
-        if getattr(svc, "_shards", None) is not None:
-            for shard in svc._shards:
-                with shard.cond:
-                    shard.pending.clear()
-        else:
-            svc._pending.clear()
+        _drop_queued(node.services.notary_service)
         if self.intent_journal is not None:
             self.intent_journal.lose_unflushed_resolutions()
         self.frozen.add(node.name)
@@ -1629,8 +1632,7 @@ class FleetSim:
         self._degraded_flushes_base += _metric_count(
             old.metrics, "Notary.DegradedFlushes"
         )
-        had_workers = bool(old._workers)
-        old.stop()   # dead worker threads must not keep flushing
+        old.stop()
         svc = BatchingNotaryService(
             node.services,
             old.uniqueness,
@@ -1641,7 +1643,6 @@ class FleetSim:
             # process ran — a sharded scenario must stay sharded or the
             # post-restart half of the soak tests a different notary
             shards=old.n_shards,
-            shard_workers=had_workers,
             degraded_fallback=old.degraded_fallback,
             intent_journal=self.intent_journal,
         )

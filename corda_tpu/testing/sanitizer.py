@@ -25,7 +25,7 @@ whose static half is ``tools/lint``:
    contention/hold profile into the process-split feasibility report
    served by ``python -m tools.lint --report split`` — which shared
    mutable state is really touched from both the pump and the
-   shard-flush pipelines, and what it costs.
+   threads around it, and what it costs.
 
 3. **Crash-schedule explorer** — :class:`CrashScheduleExplorer`
    systematically enumerates kill points at EVERY
@@ -673,10 +673,10 @@ class ConcurrencySanitizer:
 def _thread_group(thread_name: str) -> str:
     if thread_name == "MainThread":
         return "pump"
-    if thread_name.startswith("notary-shard"):
-        return "shard-flush"
     if thread_name.startswith("notary-collect"):
-        return "shard-flush"
+        return "flush-collect"
+    if thread_name.startswith("notary-client"):
+        return "client"
     if thread_name.startswith("cts-ingest"):
         return "ingest"
     if thread_name.startswith(("web", "http")):
@@ -791,14 +791,15 @@ def static_lock_view(root: Optional[str] = None) -> StaticLockView:
 
 # ---------------------------------------------------------------------------
 # the standard soak: a representative sanitized exercise of the
-# committed tree (pump tick + shard worker threads + a web-style
+# committed tree (pump tick + a client thread submitting + a web-style
 # reader), used by the CI clean-diff gate and `--report split`
 
 
 def standard_soak(issues: int = 8, shards: int = 4) -> dict:
-    """Drive a sharded BatchingNotaryService (worker threads ON — the
-    thread shape the GIL-escape split cares about) plus a concurrent
-    metrics reader through a real cash workload. The sanitizer must
+    """Drive a sharded BatchingNotaryService from this thread (the
+    pump's ticks and waves) while a client thread submits and a
+    metrics reader snapshots — the thread shape the GIL-escape split
+    cares about — through a real cash workload. The sanitizer must
     already be ARMED: every lock these objects construct reports in.
     Returns {"signed": n, "rejected": n}."""
     assert lockslib.active_monitor() is not None, (
@@ -870,7 +871,7 @@ def standard_soak(issues: int = 8, shards: int = 4) -> dict:
 
     uniq = ShardedUniquenessProvider(shards, record_decisions=True)
     svc = BatchingNotaryService(
-        notary.services, uniq, shards=shards, shard_workers=True,
+        notary.services, uniq, shards=shards,
         max_batch=4096,
         intent_journal=NotaryIntentJournal(NodeDatabase(":memory:")),
     )
@@ -880,7 +881,7 @@ def standard_soak(issues: int = 8, shards: int = 4) -> dict:
     stop_reader = threading.Event()
 
     def reader():
-        # the webserver group: snapshot reads racing the pump + workers
+        # the webserver group: snapshot reads racing the pump's waves
         # (registry.get, not counter() — a read must not become a
         # second registration site, the PR 10 fleet fix)
         while not stop_reader.is_set():
@@ -889,10 +890,26 @@ def standard_soak(issues: int = 8, shards: int = 4) -> dict:
             _ = dict(uniq.committed)
             time.sleep(0.0002)
 
+    futs: list = []
+
+    def client():
+        # a client thread: intake (journal append, lifecycle admit,
+        # shard routing) racing the pump's waves
+        for stx in stxs:
+            futs.append(svc.submit(stx, alice.party))
+            time.sleep(0.0002)
+
     rt = threading.Thread(target=reader, name="web-reader", daemon=True)
+    ct = threading.Thread(target=client, name="notary-client", daemon=True)
     rt.start()
+    ct.start()
     try:
-        futs = [svc.submit(stx, alice.party) for stx in stxs]
+        # this thread is the pump: tick until the client is done, then
+        # drain what it left
+        while ct.is_alive():
+            svc.tick()
+            time.sleep(0.0002)
+        ct.join()
         svc.flush()
     finally:
         stop_reader.set()

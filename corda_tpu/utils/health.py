@@ -1594,14 +1594,6 @@ def notary_canary_fn(services, requester_party, tracer=None):
         # and replaying a probe is meaningless anyway) — the sentinel
         # skips the journal append while staying "already stamped"
         p.intent_seq = -1
-        enqueue = getattr(svc, "enqueue_pending", None)
-        if enqueue is not None:
-            # routes to the owning SHARD on a sharded plane — a bare
-            # _pending.append would starve there (the sharded tick
-            # only drains shard queues) and trip the deadman on a
-            # healthy node
-            enqueue(p)
-        else:
-            svc._pending.append(p)
+        svc.enqueue_pending(p)
 
     return fn

@@ -300,8 +300,8 @@ class TxStory:
     ) -> int:
         """The per-flush batch event: every member transaction records
         `notary.flush` with a freshly-allocated batch id (+ owning
-        shard on the sharded plane) under ONE lock hold — the ledger
-        allocates the id so concurrent shard-worker flushes stay
+        shard on a plane of several) under ONE lock hold — the ledger
+        allocates the id, so a flush racing another story writer stays
         atomic. Returns the batch id."""
         n = len(tx_ids)
         at = self._now()

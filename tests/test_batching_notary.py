@@ -117,7 +117,7 @@ def test_streaming_tail_matches_join_path_outcomes():
             ("second", stx_second), ("bad", stx_bad),
         ):
             fut = FlowFuture()
-            svc._pending.append(
+            svc.enqueue_pending(
                 _PendingNotarisation(stx, alice.party, fut)
             )
             futs[name] = fut
@@ -306,9 +306,8 @@ def test_dispatch_failure_answers_every_requester():
 
     svc.degraded_fallback = False   # the fallback path has its own test
     futs = [FlowFuture(), FlowFuture()]
-    svc._pending = [
-        _PendingNotarisation(stx, alice.party, f) for f in futs
-    ]
+    for f in futs:
+        svc.enqueue_pending(_PendingNotarisation(stx, alice.party, f))
     svc.services._batch_verifier = BoomVerifier()
     svc.flush()   # must not raise
     for f in futs:
@@ -321,7 +320,7 @@ def test_dispatch_failure_answers_every_requester():
     # the degraded flag arms the recovery probe
     svc.degraded_fallback = True
     fut = FlowFuture()
-    svc._pending = [_PendingNotarisation(stx, alice.party, fut)]
+    svc.enqueue_pending(_PendingNotarisation(stx, alice.party, fut))
     svc.flush()
     assert hasattr(fut.result(), "by"), "degraded flush must sign"
     assert svc.degraded
@@ -371,10 +370,10 @@ def test_malformed_tx_in_batch_fails_alone():
             raise ValueError("unsupported signature scheme")
 
     bad_fut, good_fut = FlowFuture(), FlowFuture()
-    svc._pending = [
-        _PendingNotarisation(MalformedStx(), alice.party, bad_fut),
-        _PendingNotarisation(good_stx, alice.party, good_fut),
-    ]
+    svc.enqueue_pending(
+        _PendingNotarisation(MalformedStx(), alice.party, bad_fut)
+    )
+    svc.enqueue_pending(_PendingNotarisation(good_stx, alice.party, good_fut))
     svc.flush()   # must not raise
     err = bad_fut.result()
     assert isinstance(err, NotaryError)
@@ -407,7 +406,7 @@ def test_batch_deadline_holds_then_flushes():
     net.run()
     # held: requests arrived but the deadline has not aged out
     assert svc.batches_dispatched == base
-    assert len(svc._pending) == len(clients)
+    assert svc.backlog() == len(clients)
     assert all(not f.done for f in fsms)
 
     net.clock.advance(2_000_000)             # age past the deadline
@@ -485,7 +484,7 @@ class GateContract:
 
     sandbox._loaded_cache.clear()
     fut = FlowFuture()
-    svc._pending.append(_PendingNotarisation(forged, alice.party, fut))
+    svc.enqueue_pending(_PendingNotarisation(forged, alice.party, fut))
     svc.flush()
     err = fut.result()
     assert isinstance(err, NotaryError) and err.kind == "invalid-transaction"
@@ -494,7 +493,7 @@ class GateContract:
     assert att.id.bytes_ not in sandbox._loaded_cache
 
     fut = FlowFuture()
-    svc._pending.append(_PendingNotarisation(good_stx, alice.party, fut))
+    svc.enqueue_pending(_PendingNotarisation(good_stx, alice.party, fut))
     svc.flush()
     sig = fut.result()
     assert not isinstance(sig, NotaryError)
@@ -538,7 +537,7 @@ def test_flush_with_async_verifier_verifies_in_process():
 
     notary.services.transaction_verifier = NeverResolves()
     fut = FlowFuture()
-    svc._pending.append(_PendingNotarisation(stx, alice.party, fut))
+    svc.enqueue_pending(_PendingNotarisation(stx, alice.party, fut))
     svc.flush()
     sig = fut.result()
     assert not isinstance(sig, NotaryError), f"rejected: {sig}"
@@ -598,7 +597,7 @@ def convert(old_state):
 
     sandbox._upgrade_cache.clear()
     fut = FlowFuture()
-    svc._pending.append(_PendingNotarisation(forged, alice.party, fut))
+    svc.enqueue_pending(_PendingNotarisation(forged, alice.party, fut))
     svc.flush()
     err = fut.result()
     assert isinstance(err, NotaryError) and err.kind == "invalid-transaction"
@@ -607,7 +606,7 @@ def convert(old_state):
     assert att.id.bytes_ not in sandbox._upgrade_cache
 
     fut = FlowFuture()
-    svc._pending.append(_PendingNotarisation(good_stx, alice.party, fut))
+    svc.enqueue_pending(_PendingNotarisation(good_stx, alice.party, fut))
     svc.flush()
     sig = fut.result()
     assert not isinstance(sig, NotaryError), f"rejected: {sig}"

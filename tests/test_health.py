@@ -115,7 +115,7 @@ def test_wedged_flush_tick_flips_healthz_and_auto_resolves():
     # notary.* spans a firing alert will cite as evidence
     span = tracer.start_trace("notarise.frame", tx_id=str(spends[0].id))
     fut = FlowFuture()
-    svc._pending.append(
+    svc.enqueue_pending(
         _PendingNotarisation(spends[0], requester, fut, span=span)
     )
     assert svc.tick() == 1 and hasattr(fut.result(), "by")
@@ -132,7 +132,7 @@ def test_wedged_flush_tick_flips_healthz_and_auto_resolves():
 
         # the wedge: work queues, the tick loop never runs again
         fut2 = FlowFuture()
-        svc._pending.append(
+        svc.enqueue_pending(
             _PendingNotarisation(spends[1], requester, fut2)
         )
         net.clock.advance(DEADLINE + 1)
@@ -541,7 +541,7 @@ def test_canary_rides_real_flush_without_touching_uniqueness():
         hlib.notary_canary_fn(notary.services, notary.party)
     )
     monitor.tick()                       # launches: enqueues one canary
-    assert probe.launched == 1 and len(svc._pending) == 1
+    assert probe.launched == 1 and svc.backlog() == 1
     net.clock.advance(2_500)
     assert svc.tick() == 1               # a REAL flush serves it
     assert probe.completed == 1
@@ -551,7 +551,7 @@ def test_canary_rides_real_flush_without_touching_uniqueness():
     assert svc.uniqueness.committed == {}
     # ordinary traffic flushes alongside later canaries untouched
     fut = FlowFuture()
-    svc._pending.append(_PendingNotarisation(spends[0], requester, fut))
+    svc.enqueue_pending(_PendingNotarisation(spends[0], requester, fut))
     net.clock.advance(2_000)
     monitor.tick()                       # second canary joins the batch
     assert svc.tick() == 2

@@ -384,7 +384,7 @@ def test_same_tx_recommit_recovers_after_stream_tail_failure():
     for stx in spends:
         fut = FlowFuture()
         futs.append(fut)
-        svc._pending.append(_PendingNotarisation(stx, requester, fut))
+        svc.enqueue_pending(_PendingNotarisation(stx, requester, fut))
     svc.flush()
     outcomes = [f.result() for f in futs]
     assert all(isinstance(o, NotaryError) for o in outcomes), outcomes
@@ -410,7 +410,7 @@ def test_same_tx_recommit_recovers_after_stream_tail_failure():
     for stx in spends:
         fut = FlowFuture()
         retry_futs.append(fut)
-        svc._pending.append(_PendingNotarisation(stx, requester, fut))
+        svc.enqueue_pending(_PendingNotarisation(stx, requester, fut))
     svc.flush()
     for stx, fut in zip(spends, retry_futs):
         sig = fut.result()
@@ -427,7 +427,7 @@ def test_same_tx_recommit_recovers_after_stream_tail_failure():
     for stx in spends:
         fut = FlowFuture()
         futs2.append(fut)
-        svc2._pending.append(_PendingNotarisation(stx, requester, fut))
+        svc2.enqueue_pending(_PendingNotarisation(stx, requester, fut))
     svc2.flush()
     for stx, fut in zip(spends, futs2):
         sig = fut.result()

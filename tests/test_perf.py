@@ -313,7 +313,7 @@ def test_skewed_prefix_load_fires_skew_alert_with_evidence_then_resolves():
             span = tracer.start_trace("notarise.frame", tx_id=str(stx.id))
             fut = FlowFuture()
             futs.append(fut)
-            svc._enqueue_sharded(
+            svc.enqueue_pending(
                 _PendingNotarisation(stx, requester, fut, span=span)
             )
         svc.flush()
@@ -613,10 +613,8 @@ def test_node_boots_perf_plane_and_serves_attribution(tmp_path, monkeypatch):
         base = f"http://127.0.0.1:{node.web.port}"
 
         # drive the canary through a few real flushes so the notary
-        # phase timers populate. The node is SHARDED: the canary must
-        # route to a shard queue (enqueue_pending) — a bare
-        # _pending.append would starve here and trip the deadman on a
-        # perfectly healthy node
+        # phase timers populate (the node is SHARDED: the canary
+        # routes to its shard's queue through enqueue_pending)
         deadline = time.monotonic() + 10
         while time.monotonic() < deadline:
             node.pump()

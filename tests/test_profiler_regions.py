@@ -108,8 +108,7 @@ def _pending(svc):
     fut = FlowFuture()
     p = _PendingNotarisation(_Unstageable(), None, fut)
     p.intent_seq = -1       # no journal, no story
-    svc._pending.append(p)
-    svc._oldest_arrival = svc.services.clock.now_micros()
+    svc.enqueue_pending(p)
     return fut
 
 

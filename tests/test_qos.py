@@ -278,7 +278,7 @@ def test_flush_sheds_expired_pre_stage_with_typed_error():
     futs = [FlowFuture() for _ in spends]
     deadlines = [now - 1, now + 10**9, None]
     for stx, fut, dl in zip(spends, futs, deadlines):
-        svc._pending.append(
+        svc.enqueue_pending(
             _PendingNotarisation(
                 stx, requester, fut, deadline=dl, arrival_micros=now
             )
@@ -309,7 +309,7 @@ def test_shed_becomes_span_event_on_traced_frames():
     now = net.clock.now_micros()
     span = tracer.start_trace("notarise.frame")
     fut = FlowFuture()
-    svc._pending.append(
+    svc.enqueue_pending(
         _PendingNotarisation(
             spends[0], requester, fut,
             span=span, deadline=now - 1, arrival_micros=now,
@@ -336,7 +336,7 @@ def test_process_rejects_dead_on_arrival_without_queuing():
     except StopIteration as stop:
         resolved = stop.value
     assert resolved is not None and resolved.kind == qoslib.SHED_KIND
-    assert svc._pending == []
+    assert svc.backlog() == 0
     assert qos.snapshot()["shed"][qoslib.SHED_EXPIRED_INGRESS] == 1
 
 
@@ -402,7 +402,7 @@ def test_process_admission_gate_rate_shapes_flooding_client():
     shed = outcomes[2][1]
     assert shed.kind == qoslib.SHED_KIND and requester.name in shed.message
     assert qos.snapshot()["shed"][qoslib.SHED_ADMISSION] == 1
-    assert len(svc._pending) == 2
+    assert svc.backlog() == 2
 
 
 def test_verifier_worker_sheds_expired_request_pre_decode():
@@ -670,7 +670,7 @@ def test_overload_soak_sheds_holds_p99_and_reconciles():
                 (meta[id(f)], f.result(), net.clock.now_micros())
             )
         )
-        svc._pending.append(
+        svc.enqueue_pending(
             _PendingNotarisation(
                 stx, requester, fut,
                 deadline=deadline, arrival_micros=arrival,

@@ -32,11 +32,13 @@ from corda_tpu.finance.cash import (
     CashMove,
     CashState,
 )
+from corda_tpu.flows.api import FlowFuture
 from corda_tpu.node.notary import (
     BatchingNotaryService,
     InMemoryUniquenessProvider,
     ShardedUniquenessProvider,
     UniquenessConflict,
+    _PendingNotarisation,
     shard_of_ref,
     shard_of_tx,
 )
@@ -309,19 +311,18 @@ def _replay_serial(decisions, inputs_of):
     return out
 
 
-@pytest.mark.parametrize("workers", [False, True])
-def test_cross_shard_double_spend_bit_exact_vs_serial_replay(workers):
+@pytest.mark.parametrize("shards", [1, 4])
+def test_cross_shard_double_spend_bit_exact_vs_serial_replay(shards):
     """The acceptance gate: run a conflict-heavy workload (single- and
-    cross-shard rivals) through the sharded plane, then replay the
-    provider's decision log — answer order — through a serial
-    single-shard InMemoryUniquenessProvider. Accept/reject AND the
-    conflicting consumer must match decision for decision."""
-    N_SHARDS = 4
-    net, notary, alice, stxs = _conflict_workload(N_SHARDS)
-    uniq = ShardedUniquenessProvider(N_SHARDS, record_decisions=True)
+    cross-shard rivals on four shards) through the one-shard plane and
+    the four-shard plane, then replay the provider's decision log —
+    answer order — through a serial single-shard
+    InMemoryUniquenessProvider. Accept/reject AND the conflicting
+    consumer must match decision for decision."""
+    net, notary, alice, stxs = _conflict_workload(4)
+    uniq = ShardedUniquenessProvider(shards, record_decisions=True)
     svc = BatchingNotaryService(
-        notary.services, uniq,
-        shards=N_SHARDS, shard_workers=workers, max_batch=4096,
+        notary.services, uniq, shards=shards, max_batch=4096,
     )
     try:
         futs = [(stx, svc.submit(stx, alice.party)) for stx in stxs]
@@ -476,24 +477,23 @@ def test_node_boot_sharded_plane_and_sticky_layout(tmp_path):
 
 
 def test_config_validates_shard_knobs(tmp_path):
-    from corda_tpu.node.config import ConfigError, NodeConfig, write_config
+    from corda_tpu.node.config import (
+        ConfigError,
+        NodeConfig,
+        load_config,
+        write_config,
+    )
 
     with pytest.raises(ConfigError):
         NodeConfig(name="X", base_dir=".", notary="simple", notary_shards=4)
-    with pytest.raises(ConfigError):
-        NodeConfig(
-            name="X", base_dir=".", notary="batching",
-            notary_shard_workers=True,
-        )
     cfg = NodeConfig(
-        name="X", base_dir=".", notary="batching",
-        notary_shards=4, notary_shard_workers=True,
+        name="X", base_dir=".", notary="batching", notary_shards=4,
     )
     out = str(tmp_path / "node.toml")
     write_config(cfg, out)
     text = open(out).read()
     assert "notary_shards = 4" in text
-    assert "notary_shard_workers = true" in text
+    assert load_config(out).notary_shards == 4
 
 
 # ---------------------------------------------------------------------------
@@ -564,45 +564,17 @@ def test_sharded_notary_wires_qos_lanes():
 # per-shard health: one wedged shard flips /healthz, siblings keep going
 
 
-class _BlockableVerifier:
-    """CPU verifier whose verify_batch parks on an Event — the wedge."""
-
-    def __init__(self):
-        self._cpu = CpuBatchVerifier()
-        self.release = threading.Event()
-        self.release.set()
-        self.entered = threading.Event()
-
-    def verify_batch(self, requests):
-        self.entered.set()
-        assert self.release.wait(timeout=30), "test forgot to release"
-        return self._cpu.verify_batch(requests)
-
-
-def _wait_for(cond, timeout=10.0):
-    deadline = time.monotonic() + timeout
-    while time.monotonic() < deadline:
-        if cond():
-            return True
-        time.sleep(0.005)
-    return False
-
-
-def test_wedged_shard_flush_flips_healthz_and_recovers():
-    """Worker mode, 2 shards: shard A's verifier blocks mid-flush. Its
-    `notary.shard<k>.flush` heartbeat stalls past the watchdog deadline
-    -> /healthz 503 naming exactly that shard, while the OTHER shard
-    keeps beating and serving. Releasing the wedge auto-resolves."""
+def test_shard_heartbeats_carry_own_progress_and_name_a_stalled_shard():
+    """Inline waves, 2 shards: each `notary.shard<k>.flush` heartbeat
+    carries its own shard's answered requests as progress, and a shard
+    left with pending work while the pump stops ticking goes stalled
+    past the watchdog deadline, named with its queue depth; the next
+    tick serves it and /healthz recovers."""
     DEADLINE = 1_000_000
-    net, notary, alice, bank, issued, spend = _cash_rig(6)
-    blocker = _BlockableVerifier()
-    plain = CpuBatchVerifier()
-    uniq = ShardedUniquenessProvider(2)
+    net, notary, alice, bank, issued, spend = _cash_rig(8)
     svc = BatchingNotaryService(
-        notary.services, uniq,
-        shards=2, shard_workers=True,
-        shard_verifiers=[blocker, plain],
-        max_batch=4,
+        notary.services, ShardedUniquenessProvider(2),
+        shards=2, max_batch=64,
     )
     monitor = hlib.HealthMonitor(
         clock=net.clock,
@@ -613,57 +585,160 @@ def test_wedged_shard_flush_flips_healthz_and_recovers():
         spends = [spend([i], bank.party) for i in issued]
         to_zero = [s for s in spends if shard_of_tx(s, 2) == 0]
         to_one = [s for s in spends if shard_of_tx(s, 2) == 1]
-        assert to_zero and to_one, "fixture missed a shard"
+        assert len(to_zero) >= 2 and len(to_one) >= 2, "fixture missed a shard"
 
-        # healthy first: shard 1 serves normally
-        f1 = svc.submit(to_one[0], alice.party)
-        svc.flush()
-        assert hasattr(f1.result(), "by")
+        futs = [svc.submit(s, alice.party) for s in to_one]
+        futs.append(svc.submit(to_zero[0], alice.party))
+        assert svc.tick() == len(to_one) + 1   # one wave, both shards
+        assert all(hasattr(f.result(), "by") for f in futs)
         monitor.tick()
-        ok, _ = monitor.healthz()
+        ok, detail = monitor.healthz()
         assert ok
+        beats = monitor.watchdog.check(net.clock.now_micros())
+        assert beats["notary.shard0.flush"]["progress"] == 1
+        assert beats["notary.shard1.flush"]["progress"] == len(to_one)
+        assert beats["notary.flush"]["progress"] == len(to_one) + 1
 
-        # the wedge: shard 0's verifier parks its worker mid-flush
-        blocker.release.clear()
-        f0 = svc.submit(to_zero[0], alice.party)
-        with svc._shards[0].cond:
-            svc._shards[0].wake = True
-            svc._shards[0].cond.notify_all()
-        assert blocker.entered.wait(timeout=10)
+        # shard 0 holds work and the pump stops ticking
+        late = svc.submit(to_zero[1], alice.party)
         net.clock.advance(DEADLINE + 1)
+        monitor.tick()
+        ok, detail = monitor.healthz()
+        assert not ok
+        stalled = detail["unhealthy"]
+        assert "notary.shard0.flush" in stalled
+        beats = monitor.watchdog.check(net.clock.now_micros())
+        assert beats["notary.shard0.flush"]["state"] == hlib.HB_STALLED
+        assert beats["notary.shard0.flush"]["queue_depth"] == 1
+        assert beats["notary.shard1.flush"]["queue_depth"] == 0
 
-        def unhealthy_map():
-            svc.tick()       # pump alive: hub heartbeat + completions
-            monitor.tick()
-            return monitor.healthz()[1]["unhealthy"]
-
-        # shard 1 keeps beating on the advanced clock (its worker runs
-        # in real time), so only shard 0 goes stalled
-        assert _wait_for(
-            lambda: (
-                "notary.shard0.flush" in unhealthy_map()
-                and "notary.shard1.flush" not in unhealthy_map()
-            )
-        )
-        assert not monitor.healthz()[0]
-
-        # shard 1 still serves while 0 is wedged
-        f2 = svc.submit(to_one[1], alice.party)
-        with svc._shards[1].cond:
-            svc._shards[1].wake = True
-            svc._shards[1].cond.notify_all()
-        assert _wait_for(lambda: svc._drain_completions() or f2.done)
-        assert hasattr(f2.result(), "by")
-
-        # release: shard 0 finishes, beats, auto-resolves
-        blocker.release.set()
-        assert _wait_for(lambda: svc._drain_completions() or f0.done)
-        assert hasattr(f0.result(), "by")
+        # the pump comes back: the due shard flushes, progress moves
+        assert svc.tick() == 1
+        assert hasattr(late.result(), "by")
         net.clock.advance(10)
-        assert _wait_for(lambda: not unhealthy_map())
+        monitor.tick()
         assert monitor.healthz()[0]
+        beats = monitor.watchdog.check(net.clock.now_micros())
+        assert beats["notary.shard0.flush"]["progress"] == 2
+        assert beats["notary.shard1.flush"]["progress"] == len(to_one)
     finally:
-        blocker.release.set()
+        svc.stop()
+
+
+class _PerfFeed:
+    """A perf plane's notary seam, recording what each flush feeds."""
+
+    def __init__(self):
+        self.shards = None
+        self.flushes = []
+        self.waves = []
+
+    def attach_shards(self, n, depth_fns):
+        self.shards = n
+
+    def observe_flush(self, shard, n, marks):
+        self.flushes.append((shard, n))
+
+    def observe_wave(self, feeds):
+        self.waves.append([(sid, n) for sid, n, _ in feeds])
+
+    def watch_rate(self, key, fn):
+        pass
+
+
+@pytest.mark.parametrize("shards", [1, 4])
+def test_one_shard_plane_names_no_shard(shards):
+    """A one-shard batching notary looks from outside as the
+    single-queue notary always did: no Notary.Shard<k>.* metric and no
+    Notary.Shards gauge, no notary.shard<k>.flush heartbeat, no `shard`
+    attribute on its spans, no per-shard QoS lane, and one
+    observe_flush(0, ...) per flush with no wave sample. A plane of
+    several shards names each of them."""
+    from corda_tpu.node import qos as qoslib
+    from corda_tpu.utils.tracing import Tracer
+
+    net, notary, alice, stxs = _conflict_workload(4)
+    qos = qoslib.NotaryQos(qoslib.QosPolicy(max_batch=512), clock=net.clock)
+    svc = BatchingNotaryService(
+        notary.services, ShardedUniquenessProvider(shards),
+        shards=shards, qos=qos, max_batch=512,
+    )
+    monitor = hlib.HealthMonitor(clock=net.clock)
+    svc.attach_health(monitor)
+    perf = _PerfFeed()
+    svc.attach_perf(perf)
+    tracer = Tracer(enabled=True)
+    try:
+        futs = []
+        for stx in stxs:
+            fut = FlowFuture()
+            futs.append(fut)
+            svc.enqueue_pending(_PendingNotarisation(
+                stx, alice.party, fut,
+                span=tracer.start_trace("notarise.frame"),
+                arrival_micros=net.clock.now_micros(),
+            ))
+        svc.flush()
+        assert all(f.done for f in futs)
+    finally:
+        svc.stop()
+    names = svc.metrics.names()
+    shard_metrics = [n for n in names if n.startswith("Notary.Shard")]
+    beats = set(monitor.watchdog.check(net.clock.now_micros()))
+    spans = [s for t in tracer.recorder.traces() for s in t.spans]
+    assert {s.name for s in spans} >= {"notarise.frame", "notary.stage"}
+    tagged = [s for s in spans if "shard" in s.attributes]
+    if shards == 1:
+        assert shard_metrics == []
+        assert beats == {"notary.flush"}
+        assert tagged == []
+        assert qos.shard_controllers == [] and qos.snapshot()["shards"] == []
+        assert qos.controller.flushes == 1
+        assert perf.shards == 1
+        assert perf.flushes == [(0, len(stxs))] and perf.waves == []
+    else:
+        assert "Notary.Shards" in names
+        assert "Notary.Shard3.Depth" in names
+        assert beats == {"notary.flush"} | {
+            f"notary.shard{k}.flush" for k in range(shards)
+        }
+        assert len(tagged) == len(spans)
+        assert len(qos.shard_controllers) == shards
+        assert qos.controller.flushes == 0
+        assert perf.shards == shards and len(perf.waves) == 1
+
+
+@pytest.mark.parametrize("shards", [1, 4])
+def test_tick_flushes_through_the_flush_attribute(shards):
+    """The pump's tick flushes through `svc.flush`, on every plane, so
+    what an embedding rig wraps around it (a profiler annotation, an
+    fsync hook) runs on every pump flush. One flush is one wave, and
+    without QoS nothing caps it: shards past their batch flush whole.
+    The tick still counts what it answered when the wrapper returns
+    nothing."""
+    net, notary, alice, stxs = _conflict_workload(4)
+    svc = BatchingNotaryService(
+        notary.services, ShardedUniquenessProvider(shards),
+        shards=shards, max_batch=4,
+    )
+    calls = []
+    inner = svc.flush
+
+    def wrapped():
+        calls.append(svc.backlog())
+        inner()
+
+    svc.flush = wrapped
+    try:
+        futs = [svc.submit(stx, alice.party) for stx in stxs]
+        assert svc.tick() == len(stxs)
+        assert calls == [len(stxs)]
+        assert all(f.done for f in futs)
+        assert svc.batches_dispatched == min(
+            shards, len({svc.shard_of(s) for s in stxs})
+        )
+        assert svc.tick() == 0 and calls == [len(stxs)]
+    finally:
         svc.stop()
 
 

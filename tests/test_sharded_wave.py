@@ -237,7 +237,11 @@ def test_cross_shard_dvp_frames_agree_with_the_reference(bench_root):
     out = harness.run(
         args, time.monotonic(), root=bench_root, allow_cpu=True,
         overrides={
-            "config": {"committed_states": 2048, "pool_per_s": 1500},
+            # the pool outlasts what the notary can take in before its
+            # first CPU wave (several seconds) blocks intake: the ring
+            # batches drained inside the 50-ms hold, the ring and the
+            # batch in flight, about 2,000 frames with the native codec
+            "config": {"committed_states": 2048, "pool_per_s": 3000},
             "shape": {"issue_fanout": 8},
             "notary": {"verifier_batch_sizes": [32], "max_batch": 256,
                        "shards": 4},
@@ -362,6 +366,8 @@ def test_wave_region_carries_its_shards_and_frames(tmp_path):
 
 
 def test_one_shard_flush_marks_no_wave_and_no_shard(tmp_path):
+    """A one-shard flush is a wave of one: its `notary.wave` region
+    counts one shard of one, and no region names a shard."""
     plane = _Plane(tmp_path, shards=1, n=16)
 
     def body():
@@ -372,7 +378,9 @@ def test_one_shard_flush_marks_no_wave_and_no_shard(tmp_path):
         regions = _capture(tmp_path, body)
     finally:
         plane.close()
-    assert "notary.wave" not in regions
+    (_w0, _w1, wave), = regions.pop("notary.wave")
+    assert wave == {"shards": 1, "n_shards": 1, "frames": 16,
+                    "max_frames": 16}
     assert regions["notary.stage"]
     assert all(st == {} for name, evs in regions.items()
                for _, _, st in evs)

@@ -254,7 +254,7 @@ def _drive_traced_notarisation(tracer, n: int = 1):
         assert e.error is None
         fut = FlowFuture()
         futs.append(fut)
-        svc._pending.append(
+        svc.enqueue_pending(
             _PendingNotarisation(e.stx, requester, fut, span=e.span)
         )
     svc.flush()
@@ -457,7 +457,7 @@ def test_async_commit_defers_root_span_end_until_answered():
     svc.uniqueness = provider
     root = tracer.start_trace("notarise.frame", tx_id=str(spends[0].id))
     fut = FlowFuture()
-    svc._pending.append(
+    svc.enqueue_pending(
         _PendingNotarisation(spends[0], requester, fut, span=root)
     )
     svc.flush()
@@ -504,7 +504,7 @@ def test_notary_batching_counters_and_ratio_are_scrapeable():
     for s in spends:
         fut = FlowFuture()
         futs.append(fut)
-        svc._pending.append(_PendingNotarisation(s, requester, fut))
+        svc.enqueue_pending(_PendingNotarisation(s, requester, fut))
     svc.flush()
     for fut in futs:
         assert hasattr(fut.result(), "by")

@@ -338,7 +338,8 @@ def test_wal_journal_and_replay_events_reconcile_across_kill():
             "notary.admit", "wal.journal",
         ]
     # kill: pending vanishes with the heap, futures never resolve
-    svc._pending.clear()
+    for shard in svc._shards:
+        shard.pending.clear()
     # restart: a fresh service over the same WAL + the SAME ledger
     from corda_tpu.node.notary import BatchingNotaryService
 

@@ -379,8 +379,8 @@ def main(argv: Optional[list[str]] = None) -> int:
 
 def _report_split(root: str) -> int:
     """`--report split`: the runtime half. Arms the sanitizer, drives
-    the standard soak (sharded batching notary, worker threads,
-    durable intake, concurrent readers) and prints the process-split
+    the standard soak (sharded batching notary, a submitting client
+    thread, durable intake, concurrent readers) and prints the process-split
     feasibility report plus the static<->dynamic reconciliation. The
     one lint mode that imports corda_tpu (lazily — the static gate
     stays dependency-free)."""
